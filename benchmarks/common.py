@@ -26,6 +26,9 @@ from repro.core.planner import time_fn  # noqa: E402,F401
 
 def run_devices_subprocess(code: str, devices: int, timeout: int = 900) -> str:
     env = dict(os.environ)
+    # a CPU rehearsal: the child never reaches for an accelerator, which
+    # a parent process may hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     out = subprocess.run(

@@ -97,6 +97,9 @@ def main() -> None:
         "alone (with --only ''), just the schedules",
     )
     args = ap.parse_args()
+    from repro.core.compat import use_compile_cache
+
+    use_compile_cache()
     rec = None
     if args.trace:
         from repro.obs import TraceRecorder

@@ -16,10 +16,11 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import backends, plan_fft
-from repro.core.compat import make_mesh_1d
+from repro.core.compat import make_mesh_1d, use_compile_cache
 
 
 def main():
+    use_compile_cache()
     mesh = make_mesh_1d(len(jax.devices()))
     print(f"mesh: {dict(mesh.shape)}")
 

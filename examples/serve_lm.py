@@ -14,11 +14,13 @@ sys.path.insert(0, "src")
 import jax
 
 from repro.configs import ServeConfig, get_config
+from repro.core.compat import use_compile_cache
 from repro.models import Model
 from repro.serve import ServeEngine
 
 
 def main():
+    use_compile_cache()
     cfg = get_config("gemma2-9b", reduced=True)
     model = Model(cfg)
     params, _ = model.init(jax.random.PRNGKey(0))

@@ -20,10 +20,11 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import plan_fft
-from repro.core.compat import make_mesh_1d
+from repro.core.compat import make_mesh_1d, use_compile_cache
 
 
 def main():
+    use_compile_cache()
     mesh = make_mesh_1d(len(jax.devices()))
     d = 64
     rng = np.random.default_rng(0)

@@ -19,6 +19,7 @@ import numpy as np
 sys.path.insert(0, "src")
 
 from repro.configs import TrainConfig, get_config
+from repro.core.compat import use_compile_cache
 from repro.checkpoint import CheckpointManager
 from repro.data import DataConfig, SyntheticLM
 from repro.models import Model
@@ -27,6 +28,7 @@ from repro.train import init_train_state, make_train_step
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--tiny", action="store_true")

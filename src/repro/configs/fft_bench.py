@@ -1,9 +1,9 @@
 """The paper's own FFT problem configurations.
 
-Figure 4/5 strong scaling uses a 2-D FFT of size 2^14 x 2^14 (c64 = 4
+Figure 4/5 strong scaling uses a 2-D FFT of size 2^14 x 2^14 (c64 = 2
 GiB); Figure 3's chunk-size scaling sweeps the per-chunk message size on
-two nodes. Full sizes are exercised abstractly by the dry-run; the CPU
-benchmark harness uses the scaled sizes below.
+two nodes. ``chip_smoke.py`` runs the full 2-D problem on TPU v5e (one
+chip and P=4); the CPU benchmark harness uses the scaled sizes below.
 """
 
 import dataclasses

@@ -355,6 +355,11 @@ class Plan:
                     raise
         self._cache: Dict[Tuple[str, str], jax.stages.Wrapped] = {}
         self.compiles = 0  # jit wrappers created (not per-shape recompiles)
+        if local_impl == "pallas" and mesh.devices.flat[0].platform == "tpu":
+            # the kernel tiles only some local FFT lengths (kernels/ops.py);
+            # tracing the planned direction once raises for any other here,
+            # naming the length, instead of at the first execute
+            jax.eval_shape(self._fn(direction == "inverse"), self.input_spec())
 
     # -- pipelined overlap resolution -------------------------------------------
     def _pipeline_enabled(self) -> bool:

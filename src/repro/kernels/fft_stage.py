@@ -22,37 +22,68 @@ Blocking: grid (B, M/bm, N/bn); the contraction dim K (the DFT radix,
 <= MAX_DFT = 512) stays whole inside a block, so no accumulation loop is
 needed and every dot hits the MXU with K >= 128. VMEM per step at the
 default bm=bn=128, K=512: 2*(bm*K + K*bn + 2*bm*bn + bm*bn)*4B ~ 1.3 MiB,
-far under the ~128 MiB v5e budget; bn can be raised to widen the MXU N
-dim when N is large.
+double-buffered ~2.6 MiB. Every kernel here is sized against the 16 MiB
+default scoped-VMEM limit that Mosaic gives a kernel on TPU v5e (the
+chip has 128 MiB of VMEM in all); ``chunk_twiddle_pack_c64`` tiles its
+chunk for the same reason.
+
+Interpret mode is decided in one place, :func:`_pallas`: each call is
+staged for both modes and ``lax.platform_dependent`` keeps the compiled
+kernel when the program is lowered for a TPU and the Pallas interpreter
+for every other platform. No caller chooses, so nothing can run the
+interpreter on a TPU.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-# TPU memory-space hint; interpret mode ignores it.
-try:  # pragma: no cover - only resolvable with TPU support compiled in
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.MemorySpace.VMEM
-except Exception:  # pragma: no cover
-    _VMEM = None
+#: rows/columns of one ``chunk_twiddle_pack_c64`` tile: the double-buffered
+#: (tc, p, tr) output planes (p pads to 8 sublanes) stay ~2 MiB at p <= 8
+CHUNK_TILE = 128
 
 
 def _bs(shape, index_map):
-    if _VMEM is None:
-        return pl.BlockSpec(shape, index_map)
-    return pl.BlockSpec(shape, index_map, memory_space=_VMEM)
+    return pl.BlockSpec(shape, index_map, memory_space=pltpu.VMEM)
+
+
+def _out(shape, *like):
+    """Planar-f32 output struct carrying the union of the inputs' varying
+    mesh axes, so the kernels type-check inside ``shard_map``."""
+    vma = frozenset().union(*(jax.typeof(x).vma for x in like))
+    return jax.ShapeDtypeStruct(shape, jnp.float32, vma=vma)
+
+
+def _pallas(kernel, args, **spec):
+    """``pl.pallas_call(kernel, **spec)(*args)``: compiled by Mosaic when
+    lowered for a TPU, run by the Pallas interpreter on any other
+    platform (the CPU rehearsals). The only place ``interpret`` is set.
+
+    The interpreter slices varying blocks with unvarying grid indices,
+    which ``shard_map``'s vma check refuses; schedules that run these
+    kernels therefore use ``check_vma=False``
+    (:func:`repro.core.schedule.run_schedule`)."""
+
+    def call(interpret):
+        return lambda *a: pl.pallas_call(kernel, interpret=interpret, **spec)(*a)
+
+    return lax.platform_dependent(*args, tpu=call(False), default=call(True))
 
 
 def _karatsuba(wr, wi, ar, ai):
-    """(wr + i*wi) @ (ar + i*ai) via 3 real matmuls, f32 accumulate."""
-    dot = functools.partial(jnp.dot, preferred_element_type=jnp.float32)
+    """(wr + i*wi) @ (ar + i*ai) via 3 real matmuls, f32 accumulate. The
+    MXU runs f32 operands at full precision only when asked: a bf16 pass
+    would cost the transform three decimal digits."""
+    dot = functools.partial(
+        jnp.dot, preferred_element_type=jnp.float32, precision=lax.Precision.HIGHEST
+    )
     p1 = dot(wr, ar)
     p2 = dot(wi, ai)
     p3 = dot(wr + wi, ar + ai)
@@ -86,7 +117,6 @@ def stage_left(
     *,
     bm: int = 128,
     bn: int = 128,
-    interpret: bool = True,
 ) -> Tuple[jax.Array, jax.Array]:
     """Fused (W @ A) * T over planar-complex operands.
 
@@ -101,11 +131,11 @@ def stage_left(
     bn = min(bn, N)
     if M % bm or N % bn:
         raise ValueError(f"(M={M}, N={N}) must tile by (bm={bm}, bn={bn})")
-    grid = (B, M // bm, N // bn)
-    out_shape = [jax.ShapeDtypeStruct((B, M, N), jnp.float32)] * 2
-    fn = pl.pallas_call(
+    out = _out((B, M, N), ar, ai)
+    return tuple(_pallas(
         _stage_left_kernel,
-        grid=grid,
+        (wr, wi, ar, ai, tr, ti),
+        grid=(B, M // bm, N // bn),
         in_specs=[
             _bs((bm, K), lambda b, i, j: (i, 0)),  # W re
             _bs((bm, K), lambda b, i, j: (i, 0)),  # W im
@@ -118,10 +148,8 @@ def stage_left(
             _bs((1, bm, bn), lambda b, i, j: (b, i, j)),
             _bs((1, bm, bn), lambda b, i, j: (b, i, j)),
         ],
-        out_shape=out_shape,
-        interpret=interpret,
-    )
-    return tuple(fn(wr, wi, ar, ai, tr, ti))
+        out_shape=[out, out],
+    ))
 
 
 def _chunk_twiddle_pack_kernel(cr_ref, ci_ref, mr_ref, mi_ref, or_ref, oi_ref):
@@ -142,7 +170,7 @@ def _chunk_twiddle_pack_kernel(cr_ref, ci_ref, mr_ref, mi_ref, or_ref, oi_ref):
     oi_ref[0] = a * mi[None] + b * mr[None]
 
 
-def chunk_twiddle_pack_c64(chunk: jax.Array, m: jax.Array, *, interpret: bool = True) -> jax.Array:
+def chunk_twiddle_pack_c64(chunk: jax.Array, m: jax.Array) -> jax.Array:
     """Fused twiddle+pack for one arriving exchange chunk (complex64).
 
     ``chunk``: (..., rows, c) -- the raw received piece (rows of the
@@ -152,6 +180,11 @@ def chunk_twiddle_pack_c64(chunk: jax.Array, m: jax.Array, *, interpret: bool = 
     DFT stage's accumulator (see
     :func:`repro.core.transpose.transpose_then_fft`), computed in a
     single kernel launch instead of a relayout copy + twiddle multiply.
+
+    The grid tiles rows and columns by :data:`CHUNK_TILE` where they
+    divide (a whole axis otherwise), so one step's blocks fit scoped
+    VMEM at any chunk size: at 16384^2 over P=4 a chunk is 4096 x 4096
+    and its output 4x that, far beyond VMEM as a single block.
     """
     if chunk.dtype != jnp.complex64 or m.dtype != jnp.complex64:
         raise ValueError(
@@ -167,24 +200,25 @@ def chunk_twiddle_pack_c64(chunk: jax.Array, m: jax.Array, *, interpret: bool = 
     B = flat.shape[0]
     cr, ci = jnp.real(flat), jnp.imag(flat)
     mr, mi = jnp.real(m), jnp.imag(m)
-    out_shape = [jax.ShapeDtypeStruct((B, c, p, rows), jnp.float32)] * 2
-    fn = pl.pallas_call(
+    tr = CHUNK_TILE if rows % CHUNK_TILE == 0 else rows
+    tc = CHUNK_TILE if c % CHUNK_TILE == 0 else c
+    out = _out((B, c, p, rows), cr, ci, mr, mi)
+    o_re, o_im = _pallas(
         _chunk_twiddle_pack_kernel,
-        grid=(B,),
+        (cr, ci, mr, mi),
+        grid=(B, c // tc, rows // tr),
         in_specs=[
-            _bs((1, rows, c), lambda b: (b, 0, 0)),
-            _bs((1, rows, c), lambda b: (b, 0, 0)),
-            _bs((p, rows), lambda b: (0, 0)),
-            _bs((p, rows), lambda b: (0, 0)),
+            _bs((1, tr, tc), lambda b, i, j: (b, j, i)),
+            _bs((1, tr, tc), lambda b, i, j: (b, j, i)),
+            _bs((p, tr), lambda b, i, j: (0, j)),
+            _bs((p, tr), lambda b, i, j: (0, j)),
         ],
         out_specs=[
-            _bs((1, c, p, rows), lambda b: (b, 0, 0, 0)),
-            _bs((1, c, p, rows), lambda b: (b, 0, 0, 0)),
+            _bs((1, tc, p, tr), lambda b, i, j: (b, i, 0, j)),
+            _bs((1, tc, p, tr), lambda b, i, j: (b, i, 0, j)),
         ],
-        out_shape=out_shape,
-        interpret=interpret,
+        out_shape=[out, out],
     )
-    o_re, o_im = fn(cr, ci, mr, mi)
     out = jax.lax.complex(o_re, o_im)  # complex64 even under x64
     return out.reshape(lead + (c, p, rows))
 
@@ -195,7 +229,6 @@ def stage_right(
     *,
     bm: int = 128,
     bn: int = 128,
-    interpret: bool = True,
 ) -> Tuple[jax.Array, jax.Array]:
     """A @ W^T over planar-complex operands.
 
@@ -209,11 +242,11 @@ def stage_right(
     bn = min(bn, N)
     if M % bm or N % bn:
         raise ValueError(f"(M={M}, N={N}) must tile by (bm={bm}, bn={bn})")
-    grid = (B, M // bm, N // bn)
-    out_shape = [jax.ShapeDtypeStruct((B, M, N), jnp.float32)] * 2
-    fn = pl.pallas_call(
+    out = _out((B, M, N), ar, ai)
+    return tuple(_pallas(
         _stage_right_kernel,
-        grid=grid,
+        (wr, wi, ar, ai),
+        grid=(B, M // bm, N // bn),
         in_specs=[
             _bs((bn, K), lambda b, i, j: (j, 0)),  # W re (rows = output cols)
             _bs((bn, K), lambda b, i, j: (j, 0)),  # W im
@@ -224,7 +257,5 @@ def stage_right(
             _bs((1, bm, bn), lambda b, i, j: (b, i, j)),
             _bs((1, bm, bn), lambda b, i, j: (b, i, j)),
         ],
-        out_shape=out_shape,
-        interpret=interpret,
-    )
-    return tuple(fn(wr, wi, ar, ai))
+        out_shape=[out, out],
+    ))

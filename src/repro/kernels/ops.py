@@ -8,13 +8,16 @@ executed by the fused Pallas kernel (fft_stage.py):
     D = stage_right(B, W_n2)             # row DFT
     out[k1 + n1*k2] = D[k1, k2]
 
-On non-TPU backends the kernels run in interpret mode (set explicitly or
-auto-detected), which executes the kernel body op-by-op -- bitwise the
-same math, so tests/benches on CPU validate exactly what the TPU runs.
+The kernels are compiled when the program is lowered for a TPU and run in
+interpret mode on every other platform (fft_stage._pallas) -- the same
+math op by op, so tests on CPU validate what the TPU runs.
 
-Factor choice: n1 * n2 = n with both MXU-aligned where possible; the
-wrapper falls back to the pure-jnp matmul FFT for shapes the kernel
-cannot tile (non-128-multiples on TPU, primes, n < 256).
+Factor choice: n1 * n2 = n, both <= MAX_DFT, each either a multiple of
+the 128-lane tile or small enough (<= 128) to be one whole block. A
+length with no such split (n <= MAX_DFT, primes, large prime factors)
+cannot use the kernel: on a TPU that raises, naming the length (plans
+check it at plan time); the CPU rehearsal computes it with the pure-jnp
+matmul FFT instead.
 """
 
 from __future__ import annotations
@@ -24,14 +27,12 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 import repro.core.fftmath as lf
 from repro.kernels import fft_stage
 
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+#: MXU / lane tile: a factor above this must be a multiple of it
+LANE = 128
 
 
 def _split_planar(x: jax.Array):
@@ -39,23 +40,40 @@ def _split_planar(x: jax.Array):
 
 
 def _kernel_factors(n: int) -> Optional[tuple[int, int]]:
-    """Pick (n1, n2), both multiples of the MXU lane width when possible."""
-    n1 = lf.split_factor(n, lf.MAX_DFT)
-    if n1 in (0, n):
+    """Pick (n1, n2) for the kernel: both <= MAX_DFT and tileable, both
+    multiples of the lane width when possible, then the most balanced."""
+    if n <= lf.MAX_DFT:
         return None
-    n2 = n // n1
-    if n2 > lf.MAX_DFT:
-        return None
-    return n1, n2
+    best, best_key = None, None
+    for n1 in range(2, lf.MAX_DFT + 1):
+        n2, rem = divmod(n, n1)
+        if rem or n2 > lf.MAX_DFT:
+            continue
+        if any(f > LANE and f % LANE for f in (n1, n2)):
+            continue
+        key = (n1 % LANE == 0 and n2 % LANE == 0, -abs(n1 - n2), n1)
+        if best_key is None or key > best_key:
+            best, best_key = (n1, n2), key
+    return best
 
 
-@functools.partial(jax.jit, static_argnames=("inverse", "interpret", "bm", "bn"))
-def _fft_last_axis(x, *, inverse: bool, interpret: bool, bm: int, bn: int):
-    n = x.shape[-1]
+def check_tileable(n: int) -> tuple[int, int]:
+    """The kernel factorisation of length ``n``; raises naming the length
+    when the kernel cannot tile it."""
     factors = _kernel_factors(n)
-    if factors is None:  # pragma: no cover - guarded by caller
-        return lf.fft_matmul(x, inverse=inverse)
-    n1, n2 = factors
+    if factors is None:
+        raise ValueError(
+            f"the Pallas FFT kernel cannot tile a length-{n} transform: it "
+            f"needs n = n1*n2 with n1, n2 <= {lf.MAX_DFT}, each <= {LANE} or "
+            f"a multiple of {LANE} (use local_impl='jnp' or 'matmul')"
+        )
+    return factors
+
+
+@functools.partial(jax.jit, static_argnames=("inverse", "bm", "bn"))
+def _fft_last_axis(x, *, inverse: bool, bm: int, bn: int):
+    n = x.shape[-1]
+    n1, n2 = check_tileable(n)
 
     v = jnp.conj(x) if inverse else x
     lead = v.shape[:-1]
@@ -66,11 +84,10 @@ def _fft_last_axis(x, *, inverse: bool, interpret: bool, bm: int, bn: int):
 
     b_re, b_im = fft_stage.stage_left(
         _split_planar(w1), _split_planar(a), _split_planar(tw),
-        bm=min(bm, n1), bn=min(bn, n2), interpret=interpret,
+        bm=min(bm, n1), bn=min(bn, n2),
     )
     d_re, d_im = fft_stage.stage_right(
-        (b_re, b_im), _split_planar(w2),
-        bm=min(bm, n1), bn=min(bn, n2), interpret=interpret,
+        (b_re, b_im), _split_planar(w2), bm=min(bm, n1), bn=min(bn, n2),
     )
     d = d_re + 1j * d_im  # (B, k1, k2); flat index k1 + n1*k2
     out = jnp.swapaxes(d, -1, -2).reshape(lead + (n,)).astype(jnp.complex64)
@@ -83,7 +100,6 @@ def fft_last_axis(
     x: jax.Array,
     *,
     inverse: bool = False,
-    interpret: Optional[bool] = None,
     bm: int = 128,
     bn: int = 128,
 ) -> jax.Array:
@@ -91,26 +107,12 @@ def fft_last_axis(
     x = jnp.asarray(x)
     if not jnp.issubdtype(x.dtype, jnp.complexfloating):
         x = x.astype(jnp.complex64)
-    if interpret is None:
-        interpret = _default_interpret()
     n = x.shape[-1]
-    factors = _kernel_factors(n)
-    if factors is None:
+    if _kernel_factors(n) is None and jax.default_backend() != "tpu":
         return lf.fft_matmul(x, inverse=inverse)
-    n1, n2 = factors
-    if not interpret and (n1 % 128 or n2 % 128):
-        # TPU tiling wants 128-lane alignment; fall back rather than pad.
-        return lf.fft_matmul(x, inverse=inverse)
-    return _fft_last_axis(x, inverse=inverse, interpret=interpret, bm=bm, bn=bn)
+    return _fft_last_axis(x, inverse=inverse, bm=bm, bn=bn)
 
 
-def stage_left(w, a, t, **kw):
-    """Fused complex (W@A)*T -- thin public re-export (planar operands)."""
-    kw.setdefault("interpret", _default_interpret())
-    return fft_stage.stage_left(w, a, t, **kw)
-
-
-def stage_right(a, w, **kw):
-    """Complex A @ W^T -- thin public re-export (planar operands)."""
-    kw.setdefault("interpret", _default_interpret())
-    return fft_stage.stage_right(a, w, **kw)
+#: thin public re-exports (planar operands)
+stage_left = fft_stage.stage_left
+stage_right = fft_stage.stage_right

@@ -97,6 +97,9 @@ def run_subprocess(code: str, *, devices: int = 8, timeout: int = 600) -> str:
     """Run a python snippet with N host-platform devices; returns stdout.
     The snippet should print 'PASS' lines / raise on failure."""
     env = dict(os.environ)
+    # a CPU rehearsal: the child never reaches for an accelerator, which
+    # a parent process may hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     out = subprocess.run(

@@ -52,7 +52,11 @@ def test_stage_left_block_sweep(rng):
                 np.testing.assert_allclose(np.asarray(g), np.asarray(e), rtol=1e-5, atol=1e-4)
 
 
-@pytest.mark.parametrize("lead,rows,c,p", [((3,), 4, 6, 8), ((2, 5), 8, 4, 2), ((1,), 1, 3, 4)])
+@pytest.mark.parametrize(
+    "lead,rows,c,p",
+    # the last case spans a 3x2 grid of CHUNK_TILE tiles
+    [((3,), 4, 6, 8), ((2, 5), 8, 4, 2), ((1,), 1, 3, 4), ((1,), 256, 384, 4)],
+)
 def test_chunk_twiddle_pack_matches_jnp(rng, lead, rows, c, p):
     """The pipelined overlap executor's one-launch per-chunk callback:
     relayout + W_P-column x twiddle multiply == the two-op jnp path."""
@@ -90,6 +94,20 @@ def test_fft_last_axis_vs_oracle(rng, n, inverse):
     exp = np.asarray(ref.fft_last_axis_ref(jnp.asarray(x), inverse=inverse))
     scale = np.abs(exp).max() + 1e-9
     assert np.abs(got - exp).max() / scale < 2e-5
+
+
+@pytest.mark.parametrize(
+    "n,factors",
+    [(16384, (128, 128)), (4096, (64, 64)), (65536, (256, 256)), (1000, (40, 25))],
+)
+def test_kernel_factors_prefer_lane_multiples(n, factors):
+    assert ops.check_tileable(n) == factors
+
+
+@pytest.mark.parametrize("n", [512, 1021, 129 * 129])
+def test_check_tileable_names_the_length(n):
+    with pytest.raises(ValueError, match=f"length-{n} transform"):
+        ops.check_tileable(n)
 
 
 def test_fft_last_axis_fallback_odd_size(rng):
