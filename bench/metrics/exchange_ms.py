@@ -1,0 +1,9 @@
+"""Device milliseconds per transform in collectives, on the slowest
+chip."""
+
+
+def read(ctx):
+    if ctx.trace is None:
+        return None
+    per_step = ctx.trace.per_step_max(lambda d: d.class_s("exchange"))
+    return 1e3 * per_step if per_step else None
