@@ -31,6 +31,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs import trace as obs
+
 LocalImpl = Literal["jnp", "matmul", "pallas"]
 
 #: Largest direct DFT-matrix applied as a single matmul. 512 keeps the
@@ -131,14 +133,20 @@ def local_fft(
     ``jnp``    -- oracle / reference (XLA's own FFT op).
     ``matmul`` -- four-step DFT matmuls (MXU formulation, pure jnp).
     ``pallas`` -- the fused Pallas kernel (kernels/fft_stage.py).
+
+    Every op it traces runs under the ``repro.local_fft`` layer scope.
     """
-    x = jnp.asarray(x)
-    if not jnp.issubdtype(x.dtype, jnp.complexfloating):
-        x = x.astype(jnp.complex64)
-    if axis != -1 and axis != x.ndim - 1:
-        x = jnp.moveaxis(x, axis, -1)
-        y = local_fft(x, axis=-1, inverse=inverse, impl=impl, max_dft=max_dft)
-        return jnp.moveaxis(y, -1, axis)
+    with obs.layer(obs.LOCAL_FFT):
+        x = jnp.asarray(x)
+        if not jnp.issubdtype(x.dtype, jnp.complexfloating):
+            x = x.astype(jnp.complex64)
+        if axis != -1 and axis != x.ndim - 1:
+            y = _local_fft_last(jnp.moveaxis(x, axis, -1), inverse, impl, max_dft)
+            return jnp.moveaxis(y, -1, axis)
+        return _local_fft_last(x, inverse, impl, max_dft)
+
+
+def _local_fft_last(x: jax.Array, inverse: bool, impl: LocalImpl, max_dft: int) -> jax.Array:
     if impl == "jnp":
         return jnp.fft.ifft(x, norm="backward") if inverse else jnp.fft.fft(x)
     if impl == "matmul":

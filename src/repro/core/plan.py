@@ -46,6 +46,7 @@ import repro.core.schedule as sch
 from repro.core import backends
 from repro.core import comm_model as cm
 from repro.core.distributed_fft import FFTConfig
+from repro.obs import trace as obs
 
 #: Pair-key separator for pencil backend pairs ("scatter+bisection") --
 #: registry names are identifiers, so '+' cannot appear inside one.
@@ -355,6 +356,7 @@ class Plan:
                     raise
         self._cache: Dict[Tuple[str, str], jax.stages.Wrapped] = {}
         self.compiles = 0  # jit wrappers created (not per-shape recompiles)
+        self.calls = 0  # execute/inverse calls, the id of each repro.execute span
         if local_impl == "pallas" and mesh.devices.flat[0].platform == "tpu":
             # the kernel tiles only some local FFT lengths (kernels/ops.py);
             # tracing the planned direction once raises for any other here,
@@ -1016,26 +1018,26 @@ class Plan:
         """Run the planned direction through the cached executable (or,
         while a :attr:`faults` plan is armed, through the segmented
         chaos executor so injected failures fire deterministically)."""
-        x = jnp.asarray(x)
-        inv = self.direction == "inverse"
-        if self._faults_armed():
-            return sch.run_schedule(
-                x, self.schedule(inv), self.mesh,
-                impl=self.local_impl, faults=self.faults,
-            )
-        return self._executable(inv, x.dtype)(x)
+        return self._run(x, self.direction == "inverse")
 
     def inverse(self, x: jax.Array) -> jax.Array:
         """Run the opposite of the planned direction. Not available for
         ``ndim=1`` (raises before executing anything -- see class doc)."""
-        x = jnp.asarray(x)
-        inv = self.direction != "inverse"
-        if self._faults_armed():
-            return sch.run_schedule(
-                x, self.schedule(inv), self.mesh,
-                impl=self.local_impl, faults=self.faults,
-            )
-        return self._executable(inv, x.dtype)(x)
+        return self._run(x, self.direction != "inverse")
+
+    def _run(self, x: jax.Array, inv: bool) -> jax.Array:
+        """The host work of one call, from entry to the return of the
+        jitted call, under the host span ``repro.execute`` numbered by
+        :attr:`calls`."""
+        self.calls += 1
+        with obs.span(obs.EXECUTE, call=self.calls):
+            x = jnp.asarray(x)
+            if self._faults_armed():
+                return sch.run_schedule(
+                    x, self.schedule(inv), self.mesh,
+                    impl=self.local_impl, faults=self.faults,
+                )
+            return self._executable(inv, x.dtype)(x)
 
     def executable_stats(self) -> Dict[Tuple[str, str], int]:
         """(direction, dtype) -> number of compiled specializations held

@@ -61,6 +61,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 import repro.core.fftmath as lf
 import repro.core.transpose as tr
 from repro.core.compat import shard_map
+from repro.obs import trace as obs
 
 
 # ---------------------------------------------------------------------------
@@ -877,23 +878,32 @@ def _pencil_real(shape, ndim, inverse, row, col, pr, pc, br, bc, fused, n_chunks
 def local_rfft(x: jax.Array, impl) -> jax.Array:
     """r2c along the last axis. ``jnp`` uses the native rfft; the matmul
     and pallas impls have no r2c codelet, so they transform the
-    complexified axis and keep the non-redundant half."""
+    complexified axis and keep the non-redundant half. All of it runs
+    under the ``repro.local_fft`` layer scope (entered once per op:
+    ``lf.local_fft`` enters its own)."""
     if impl == "jnp":
-        return jnp.fft.rfft(x, axis=-1)
-    return lf.local_fft(x, axis=-1, impl=impl)[..., : rfft_len(x.shape[-1])]
+        with obs.layer(obs.LOCAL_FFT):
+            return jnp.fft.rfft(x, axis=-1)
+    y = lf.local_fft(x, axis=-1, impl=impl)
+    with obs.layer(obs.LOCAL_FFT):
+        return y[..., : rfft_len(x.shape[-1])]
 
 
 def local_irfft(x: jax.Array, n: int, impl) -> jax.Array:
     """c2r along the last axis: half spectrum (length ``n//2+1``) to a
-    real length-``n`` signal, carrying the 1/n factor."""
-    if impl == "jnp":
-        return jnp.fft.irfft(x, n=n, axis=-1)
-    h = x.shape[-1]
-    # rebuild the redundant half (X[n-k] = conj(X[k]), k = 1..n-h) and
-    # run the impl's c2c inverse; the result is real up to roundoff
-    tail = jnp.conj(x[..., 1 : n - h + 1])[..., ::-1]
-    full = jnp.concatenate([x, tail], axis=-1)
-    return jnp.real(lf.local_fft(full, axis=-1, inverse=True, impl=impl))
+    real length-``n`` signal, carrying the 1/n factor; scoped as
+    :func:`local_rfft`."""
+    with obs.layer(obs.LOCAL_FFT):
+        if impl == "jnp":
+            return jnp.fft.irfft(x, n=n, axis=-1)
+        h = x.shape[-1]
+        # rebuild the redundant half (X[n-k] = conj(X[k]), k = 1..n-h) and
+        # run the impl's c2c inverse; the result is real up to roundoff
+        tail = jnp.conj(x[..., 1 : n - h + 1])[..., ::-1]
+        full = jnp.concatenate([x, tail], axis=-1)
+    y = lf.local_fft(full, axis=-1, inverse=True, impl=impl)
+    with obs.layer(obs.LOCAL_FFT):
+        return jnp.real(y)
 
 
 def pad_last(v: jax.Array, count: int) -> jax.Array:
@@ -907,7 +917,12 @@ def pad_last(v: jax.Array, count: int) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
-def _relayout(v: jax.Array, st: Relayout) -> jax.Array:
+def _relayout(v: jax.Array, st) -> jax.Array:
+    """A Relayout, HermitianPack or Trim stage: local data movement."""
+    if isinstance(st, HermitianPack):
+        return pad_last(v, st.hp - st.h)
+    if isinstance(st, Trim):
+        return v[..., : st.h]
     if st.op == "swap_last2":
         return jnp.swapaxes(v, -1, -2)
     if st.op == "swap_outer":
@@ -920,72 +935,82 @@ def _relayout(v: jax.Array, st: Relayout) -> jax.Array:
     raise ValueError(f"unknown relayout op {st.op!r}")
 
 
-def _twiddled_exchange(v: jax.Array, tw: Twiddle, ex: Exchange) -> jax.Array:
-    """Twiddle + the exchange it rides: fused into the per-chunk compute
-    on streaming backends (applied to each sub-chunk as it arrives),
-    up-front to the whole block otherwise."""
+def _twiddled_exchange(v: jax.Array, tw: Twiddle, ex: Exchange, index: int) -> jax.Array:
+    """Twiddle + the exchange it rides (stages ``index`` and
+    ``index + 1``): fused into the per-chunk compute on streaming
+    backends (applied to each sub-chunk as it arrives; its ops nest the
+    Twiddle's stage scope inside the Exchange's), up-front to the whole
+    block otherwise."""
     from repro.core import backends
 
     n, r, c, p = tw.n, tw.r, tw.c, ex.p
-    me = lax.axis_index(ex.axis)
+    with obs.stage(index, tw), obs.layer(obs.TWIDDLE):
+        me = lax.axis_index(ex.axis)
     if backends.get(ex.backend).supports_chunk_fn:
 
         def tw_chunk(chunk: jax.Array, src: jax.Array, offset: int) -> jax.Array:
             # chunk (..., R/p, rows): my k1 block x src's j2 rows
             # [offset, offset+rows) of its C/p block.
-            k1 = me * (r // p) + jnp.arange(r // p)
-            j2 = src * (c // p) + offset + jnp.arange(chunk.shape[-1])
-            t = jnp.exp(-2j * jnp.pi * (k1[:, None] * j2[None, :]) / n)
-            return chunk * t.astype(chunk.dtype)
+            with obs.stage(index, tw), obs.layer(obs.TWIDDLE):
+                k1 = me * (r // p) + jnp.arange(r // p)
+                j2 = src * (c // p) + offset + jnp.arange(chunk.shape[-1])
+                t = jnp.exp(-2j * jnp.pi * (k1[:, None] * j2[None, :]) / n)
+                return chunk * t.astype(chunk.dtype)
 
-        return tr.distributed_transpose(
-            v, ex.axis, strategy=ex.backend, chunk_fn=tw_chunk, n_chunks=ex.n_chunks
-        )
-    j2 = me * (c // p) + jnp.arange(c // p)
-    k1 = jnp.arange(r)
-    t = jnp.exp(-2j * jnp.pi * (j2[:, None] * k1[None, :]) / n).astype(v.dtype)
-    return tr.distributed_transpose(v * t, ex.axis, strategy=ex.backend)
+        with obs.stage(index + 1, ex):
+            return tr.distributed_transpose(
+                v, ex.axis, strategy=ex.backend, chunk_fn=tw_chunk, n_chunks=ex.n_chunks
+            )
+    with obs.stage(index, tw), obs.layer(obs.TWIDDLE):
+        j2 = me * (c // p) + jnp.arange(c // p)
+        k1 = jnp.arange(r)
+        t = jnp.exp(-2j * jnp.pi * (j2[:, None] * k1[None, :]) / n).astype(v.dtype)
+        v = v * t
+    with obs.stage(index + 1, ex):
+        return tr.distributed_transpose(v, ex.axis, strategy=ex.backend)
 
 
-def _execute_stages(v: jax.Array, stages: Tuple[object, ...], *, impl="jnp") -> jax.Array:
-    """Interpret a run of stages over one device's local block. The
-    whole-schedule executor and the trace-mode segment runner both call
-    this, so traced segments execute exactly the ops the untraced body
-    would."""
+def _execute_stages(
+    v: jax.Array, stages: Tuple[object, ...], *, impl="jnp", start: int = 0
+) -> jax.Array:
+    """Interpret a run of stages over one device's local block; ``start``
+    is the index of ``stages[0]`` in its schedule. The whole-schedule
+    executor and the trace-mode segment runner both call this, so traced
+    segments execute exactly the ops the untraced body would. Each
+    stage runs under its stage scope ``repro.stage<index>.<Kind>``, and
+    its ops under their layer scopes."""
     i = 0
     while i < len(stages):
         st = stages[i]
-        if isinstance(st, LocalFFT):
-            v = lf.local_fft(v, axis=st.axis, inverse=st.inverse, impl=impl)
-        elif isinstance(st, LocalR2C):
-            v = local_rfft(v, impl)
-        elif isinstance(st, LocalC2R):
-            v = local_irfft(v, st.n_last, impl)
-        elif isinstance(st, HermitianPack):
-            v = pad_last(v, st.hp - st.h)
-        elif isinstance(st, Trim):
-            v = v[..., : st.h]
-        elif isinstance(st, Relayout):
-            v = _relayout(v, st)
-        elif isinstance(st, Twiddle):
+        if isinstance(st, Twiddle):
             nxt = stages[i + 1] if i + 1 < len(stages) else None
             if not isinstance(nxt, Exchange):
                 raise ValueError("Twiddle must immediately precede an Exchange")
-            v = _twiddled_exchange(v, st, nxt)
+            v = _twiddled_exchange(v, st, nxt, start + i)
             i += 2
             continue
-        elif isinstance(st, Exchange):
-            if st.fft:
-                v = tr.transpose_then_fft(
-                    v, st.axis, strategy=st.backend, impl=impl,
-                    fused=st.fused, n_chunks=st.n_chunks, inverse=st.inverse,
-                )
+        with obs.stage(start + i, st):
+            if isinstance(st, LocalFFT):
+                v = lf.local_fft(v, axis=st.axis, inverse=st.inverse, impl=impl)
+            elif isinstance(st, LocalR2C):
+                v = local_rfft(v, impl)
+            elif isinstance(st, LocalC2R):
+                v = local_irfft(v, st.n_last, impl)
+            elif isinstance(st, (HermitianPack, Trim, Relayout)):
+                with obs.layer(obs.RELAYOUT):
+                    v = _relayout(v, st)
+            elif isinstance(st, Exchange):
+                if st.fft:
+                    v = tr.transpose_then_fft(
+                        v, st.axis, strategy=st.backend, impl=impl,
+                        fused=st.fused, n_chunks=st.n_chunks, inverse=st.inverse,
+                    )
+                else:
+                    v = tr.distributed_transpose(
+                        v, st.axis, strategy=st.backend, n_chunks=st.n_chunks
+                    )
             else:
-                v = tr.distributed_transpose(
-                    v, st.axis, strategy=st.backend, n_chunks=st.n_chunks
-                )
-        else:
-            raise TypeError(f"unknown stage {st!r}")
+                raise TypeError(f"unknown stage {st!r}")
         i += 1
     return v
 
@@ -993,13 +1018,18 @@ def _execute_stages(v: jax.Array, stages: Tuple[object, ...], *, impl="jnp") -> 
 def execute_schedule(xl: jax.Array, sched: Schedule, *, impl="jnp") -> jax.Array:
     """Interpret a schedule over one device's local block -- the single
     shard_map body behind every distributed transform. Must be called
-    inside ``shard_map`` (use :func:`run_schedule` from outside)."""
-    v = jnp.conj(xl) if sched.conj else xl
-    v = _execute_stages(v, sched.stages, impl=impl)
+    inside ``shard_map`` (use :func:`run_schedule` from outside). The
+    conj/scale prologue and epilogue, outside every stage, run under the
+    ``repro.relayout`` layer scope."""
     if sched.conj:
-        v = jnp.conj(v)
-    if sched.scale is not None:
-        v = v / sched.scale
+        with obs.layer(obs.RELAYOUT):
+            xl = jnp.conj(xl)
+    v = _execute_stages(xl, sched.stages, impl=impl)
+    with obs.layer(obs.RELAYOUT):
+        if sched.conj:
+            v = jnp.conj(v)
+        if sched.scale is not None:
+            v = v / sched.scale
     return v
 
 
@@ -1265,7 +1295,7 @@ def _segment_executable(
     spans would time the compiler, not the stage."""
     seg = sched.stages[start : start + seg_len]
     return jax.jit(shard_map(
-        lambda xl: _execute_stages(xl, seg, impl=impl),
+        lambda xl: _execute_stages(xl, seg, impl=impl, start=start),
         mesh=mesh, in_specs=in_spec, out_specs=out_spec, check_vma=_check_vma(impl),
     ))
 

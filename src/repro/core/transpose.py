@@ -43,6 +43,11 @@ against each other and a numpy routing simulation in tests.
 Inside ``shard_map`` the local block is ``(..., r, C)`` where the global
 rows ``R = P*r`` are sharded over ``axis_name``; the transposed result is
 ``(..., c, R)`` with the global columns ``C = P*c`` now sharded.
+
+Layer scopes (:mod:`repro.obs.trace`): the collective call alone runs
+under ``repro.exchange``; the local transposes, packs and unpacks
+around it under ``repro.relayout``; a chunk_fn scopes its own work
+(the fused DFT's per-chunk compute is ``repro.local_fft``).
 """
 
 from __future__ import annotations
@@ -55,6 +60,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.core.compat import axis_size as _axis_size
+from repro.obs import trace as obs
 
 #: A registered backend name (see ``repro.core.backends.available()``).
 #: Plain ``str`` on purpose: the registry, not a hand-kept enumeration,
@@ -77,20 +83,28 @@ def _split_chunks(x: jax.Array, p: int) -> jax.Array:
     """(..., r, C) -> (p, ..., r, c): chunk j holds columns [j*c, (j+1)*c)."""
     *lead, r, C = x.shape
     c = C // p
-    x = x.reshape(*lead, r, p, c)
-    return jnp.moveaxis(x, -2, 0)
+    with obs.layer(obs.RELAYOUT):
+        x = x.reshape(*lead, r, p, c)
+        return jnp.moveaxis(x, -2, 0)
 
 
 def _merge_rows(chunks: jax.Array) -> jax.Array:
     """(p, ..., r, c) -> (..., p*r, c): stack chunk j as rows [j*r, (j+1)*r)."""
     p = chunks.shape[0]
-    chunks = jnp.moveaxis(chunks, 0, -3)  # (..., p, r, c)
-    *lead, _, r, c = chunks.shape
-    return chunks.reshape(*lead, p * r, c)
+    with obs.layer(obs.RELAYOUT):
+        chunks = jnp.moveaxis(chunks, 0, -3)  # (..., p, r, c)
+        *lead, _, r, c = chunks.shape
+        return chunks.reshape(*lead, p * r, c)
 
 
 def _transpose_local(x: jax.Array) -> jax.Array:
-    return jnp.swapaxes(x, -1, -2)
+    with obs.layer(obs.RELAYOUT):
+        return jnp.swapaxes(x, -1, -2)
+
+
+def _ppermute(x: jax.Array, axis_name: str, perm) -> jax.Array:
+    with obs.layer(obs.EXCHANGE):
+        return lax.ppermute(x, axis_name, perm)
 
 
 # ---------------------------------------------------------------------------
@@ -145,7 +159,10 @@ def _call_chunk_fn(fn: ChunkFn, arity: int, chunk, src, offset: int):
 
 def _alltoall(x: jax.Array, axis_name: str) -> jax.Array:
     # (..., r, C) --split cols/concat rows--> (..., R, c) --local T--> (..., c, R)
-    y = lax.all_to_all(x, axis_name, split_axis=x.ndim - 1, concat_axis=x.ndim - 2, tiled=True)
+    with obs.layer(obs.EXCHANGE):
+        y = lax.all_to_all(
+            x, axis_name, split_axis=x.ndim - 1, concat_axis=x.ndim - 2, tiled=True
+        )
     return _transpose_local(y)
 
 
@@ -178,7 +195,8 @@ def _chunked_exchange(
     without explicit buffer management.
     """
     p = _axis_size(axis_name)
-    me = lax.axis_index(axis_name)
+    with obs.layer(obs.RELAYOUT):
+        me = lax.axis_index(axis_name)
     chunks = _split_chunks(x, p)  # (p, ..., r, c)
     r, c = x.shape[-2], x.shape[-1] // p
     q = subchunks_per_peer(r, p, n_chunks)
@@ -187,7 +205,8 @@ def _chunked_exchange(
     per_sub = chunk_fn is None or arity >= 3
 
     def sub(block: jax.Array, t: int) -> jax.Array:
-        return lax.slice_in_dim(block, t * rq, (t + 1) * rq, axis=-2)
+        with obs.layer(obs.RELAYOUT):
+            return lax.slice_in_dim(block, t * rq, (t + 1) * rq, axis=-2)
 
     def process(piece: jax.Array, src: jax.Array, offset: int) -> jax.Array:
         out = _transpose_local(piece)  # (..., c, rows)
@@ -203,7 +222,7 @@ def _chunked_exchange(
             for t in range(q):
                 piece = sub(block, t)
                 if perm is not None:
-                    piece = lax.ppermute(piece, axis_name, perm)
+                    piece = _ppermute(piece, axis_name, perm)
                 parts.append((src, t * rq, process(piece, src, t * rq)))
         else:
             # 2-arg chunk_fn: stream the transport, process the whole
@@ -212,23 +231,31 @@ def _chunked_exchange(
             for t in range(q):
                 piece = sub(block, t)
                 if perm is not None:
-                    piece = lax.ppermute(piece, axis_name, perm)
+                    piece = _ppermute(piece, axis_name, perm)
                 pieces.append(_transpose_local(piece))
-            whole = pieces[0] if q == 1 else jnp.concatenate(pieces, axis=-1)
+            with obs.layer(obs.RELAYOUT):
+                whole = pieces[0] if q == 1 else jnp.concatenate(pieces, axis=-1)
             parts.append((src, 0, chunk_fn(whole, src)))
 
     # Own chunk (round 0) -- compute immediately, no communication.
-    rounds(jnp.take(chunks, me, axis=0), me)
+    with obs.layer(obs.RELAYOUT):
+        own = jnp.take(chunks, me, axis=0)
+    rounds(own, me)
     for s in range(1, p):
-        perm, send_slot, src = schedule(me, s, p)
-        rounds(jnp.take(chunks, send_slot, axis=0), src, perm)
+        with obs.layer(obs.RELAYOUT):
+            perm, send_slot, src = schedule(me, s, p)
+            send = jnp.take(chunks, send_slot, axis=0)
+        rounds(send, src, perm)
 
     # Assemble (..., c, R): the piece from src j at row offset o supplies
     # columns [j*r + o, j*r + o + rows).
     out_shape = x.shape[:-2] + (c, p * r)
-    out = jnp.zeros(out_shape, parts[0][2].dtype)
-    for src, off, part in parts:
-        out = lax.dynamic_update_slice_in_dim(out, part, src * r + off, axis=out.ndim - 1)
+    with obs.layer(obs.RELAYOUT):
+        out = jnp.zeros(out_shape, parts[0][2].dtype)
+        for src, off, part in parts:
+            out = lax.dynamic_update_slice_in_dim(
+                out, part, src * r + off, axis=out.ndim - 1
+            )
     return out
 
 
@@ -251,26 +278,36 @@ def _chunked_reduce(
     concatenate along the last axis across offsets. Sub-chunking via
     ``n_chunks`` splits each peer block so compute streams into flight
     time even at small P.
+
+    The sum over sources is the fused DFT's and runs under the
+    ``repro.local_fft`` layer scope.
     """
     p = _axis_size(axis_name)
-    me = lax.axis_index(axis_name)
+    with obs.layer(obs.RELAYOUT):
+        me = lax.axis_index(axis_name)
     chunks = _split_chunks(x, p)  # (p, ..., r, c)
     r = x.shape[-2]
     q = subchunks_per_peer(r, p, n_chunks)
     rq = r // q
 
     def sub(block: jax.Array, t: int) -> jax.Array:
-        return lax.slice_in_dim(block, t * rq, (t + 1) * rq, axis=-2)
+        with obs.layer(obs.RELAYOUT):
+            return lax.slice_in_dim(block, t * rq, (t + 1) * rq, axis=-2)
 
-    own = jnp.take(chunks, me, axis=0)
+    with obs.layer(obs.RELAYOUT):
+        own = jnp.take(chunks, me, axis=0)
     parts = [chunk_fn(sub(own, t), me, t * rq) for t in range(q)]
     for s in range(1, p):
-        perm, send_slot, src = schedule(me, s, p)
-        send = jnp.take(chunks, send_slot, axis=0)
+        with obs.layer(obs.RELAYOUT):
+            perm, send_slot, src = schedule(me, s, p)
+            send = jnp.take(chunks, send_slot, axis=0)
         for t in range(q):
-            recv = lax.ppermute(sub(send, t), axis_name, perm)
-            parts[t] = parts[t] + chunk_fn(recv, src, t * rq)
-    return parts[0] if q == 1 else jnp.concatenate(parts, axis=-1)
+            recv = _ppermute(sub(send, t), axis_name, perm)
+            got = chunk_fn(recv, src, t * rq)
+            with obs.layer(obs.LOCAL_FFT):
+                parts[t] = parts[t] + got
+    with obs.layer(obs.RELAYOUT):
+        return parts[0] if q == 1 else jnp.concatenate(parts, axis=-1)
 
 
 def _ring_schedule(me, s, p):
@@ -311,12 +348,12 @@ def _bisection(x: jax.Array, axis_name: str) -> jax.Array:
     received chunks by source rank.
     """
     p = _axis_size(axis_name)
-    me = lax.axis_index(axis_name)
     chunks = _split_chunks(x, p)  # (p, ..., r, c), slot d = chunk destined to d
-    r = x.shape[-2]
 
     # Phase 1: rotate so slot j holds destination (me + j) mod p.
-    buf = jnp.roll(chunks, -me, axis=0)
+    with obs.layer(obs.RELAYOUT):
+        me = lax.axis_index(axis_name)
+        buf = jnp.roll(chunks, -me, axis=0)
 
     # Phase 2: log rounds of exchange with rank (me + 2^t). The travelling
     # slot set {j : bit t of j set} is static and identical on every rank,
@@ -326,12 +363,16 @@ def _bisection(x: jax.Array, axis_name: str) -> jax.Array:
         step = 1 << t
         idx = tuple(j for j in range(p) if (j >> t) & 1)
         perm = [(i, (i + step) % p) for i in range(p)]
-        recv = lax.ppermute(buf[idx, ...], axis_name, perm)
-        buf = buf.at[idx, ...].set(recv)
+        with obs.layer(obs.RELAYOUT):
+            travelling = buf[idx, ...]
+        recv = _ppermute(travelling, axis_name, perm)
+        with obs.layer(obs.RELAYOUT):
+            buf = buf.at[idx, ...].set(recv)
         t += 1
 
     # Phase 3: slot j now holds the chunk from source (me - j) mod p.
-    by_src = jnp.flip(jnp.roll(buf, -(me + 1), axis=0), axis=0)  # slot s = from rank s
+    with obs.layer(obs.RELAYOUT):
+        by_src = jnp.flip(jnp.roll(buf, -(me + 1), axis=0), axis=0)  # slot s = from rank s
     stacked = _merge_rows(by_src)  # (..., R, c)
     return _transpose_local(stacked)  # (..., c, R)
 
@@ -469,31 +510,37 @@ def transpose_then_fft(
 
     r = x.shape[-2]
     cdtype = jnp.result_type(x.dtype, jnp.complex64)
-    w_p = jnp.asarray(lf.dft_matrix(p, cdtype))  # (k1, src)
-    tw = jnp.asarray(lf.twiddle(p, r, cdtype))  # (k1, j2)
-    if inverse:
-        w_p, tw = jnp.conj(w_p), jnp.conj(tw)
+    with obs.layer(obs.LOCAL_FFT):
+        w_p = jnp.asarray(lf.dft_matrix(p, cdtype))  # (k1, src)
+        tw = jnp.asarray(lf.twiddle(p, r, cdtype))  # (k1, j2)
+        if inverse:
+            w_p, tw = jnp.conj(w_p), jnp.conj(tw)
+        x = x.astype(cdtype)
 
     use_pallas = impl == "pallas" and jnp.dtype(cdtype) == jnp.complex64
 
     def chunk_fn(chunk: jax.Array, src: jax.Array, offset: int) -> jax.Array:
         # chunk (..., rows, c) = rows [offset, offset+rows) of src's block.
         rows = chunk.shape[-2]
-        col = lax.dynamic_slice_in_dim(w_p, src, 1, axis=1)[:, 0]  # (k1=p,)
-        tws = lax.slice_in_dim(tw, offset, offset + rows, axis=1)  # (p, rows)
-        m = col[:, None] * tws  # (k1, j2) for this piece
-        if use_pallas:
-            from repro.kernels import fft_stage
+        with obs.layer(obs.LOCAL_FFT):
+            col = lax.dynamic_slice_in_dim(w_p, src, 1, axis=1)[:, 0]  # (k1=p,)
+            tws = lax.slice_in_dim(tw, offset, offset + rows, axis=1)  # (p, rows)
+            m = col[:, None] * tws  # (k1, j2) for this piece
+            if use_pallas:
+                from repro.kernels import fft_stage
 
-            return fft_stage.chunk_twiddle_pack_c64(chunk, m)
+                return fft_stage.chunk_twiddle_pack_c64(chunk, m)
         ct = _transpose_local(chunk)  # (..., c, rows)
-        return ct[..., None, :] * m  # (..., c, k1=p, j2=rows)
+        with obs.layer(obs.LOCAL_FFT):
+            return ct[..., None, :] * m  # (..., c, k1=p, j2=rows)
 
-    acc = backend.stream_reduce(x.astype(cdtype), axis_name, chunk_fn, n_chunks=n_chunks)
+    acc = backend.stream_reduce(x, axis_name, chunk_fn, n_chunks=n_chunks)
     acc = lf.local_fft(acc, axis=-1, inverse=inverse, impl=impl)  # j2 -> k2 (1/r if inverse)
     # F index k = k1 + P*k2 -> order (k2 major, k1 minor).
     out = _transpose_local(acc)  # (..., c, k2=r, k1=p)
-    out = out.reshape(out.shape[:-2] + (p * r,))
+    with obs.layer(obs.RELAYOUT):
+        out = out.reshape(out.shape[:-2] + (p * r,))
     if inverse:
-        out = out / p  # completes the 1/(p*r) = 1/R factor
+        with obs.layer(obs.LOCAL_FFT):
+            out = out / p  # completes the 1/(p*r) = 1/R factor
     return out

@@ -4,12 +4,11 @@ from repro.obs.history import (
     read_history,
     snapshot_from_bench,
 )
-from repro.obs.trace import Span, TraceRecorder, merge_traces
+from repro.obs.trace import Span, TraceRecorder
 
 __all__ = [
     "Span",
     "TraceRecorder",
-    "merge_traces",
     "snapshot_from_bench",
     "append_snapshot",
     "read_history",

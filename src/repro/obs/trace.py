@@ -1,45 +1,82 @@
-"""Tracing primitives for the stage-schedule executor -- the repo's
-APEX analogue.
+"""Names the program puts on the JAX profiler's timeline, and the
+stage-span recorder of the segmented executor -- the repo's APEX
+analogue.
 
 The paper's breakdown (communication vs local FFT compute, per
-parcelport) is a *timeline* result: HPX ships task-level instrumentation
-(APEX) that stamps wall-clock spans around every task so cost can be
-attributed to the operation that incurred it. Our tasks are the Stage
-records of the schedule IR, so the tracer is deliberately tiny: a
-:class:`TraceRecorder` collects :class:`Span` records (name + wall-clock
-start/duration + free-form ``args``) and counter samples, and exports
-them as Chrome-trace JSON (loadable in ``chrome://tracing`` or
-https://ui.perfetto.dev) or as one-JSON-object-per-line JSONL for
-machine consumption.
+parcelport) is a *timeline* result. Here the timeline is the JAX
+profiler's trace (``jax.profiler.start_trace``): host spans and device
+operations share its clock, and the program names its own work in it.
 
-Producers:
+Device scopes (``jax.named_scope``; they land in every compiled
+instruction's ``op_name`` metadata when the program is traced and cost
+nothing at run time):
 
-- ``run_schedule(..., trace=rec)`` stamps one span per schedule stage
-  (per-Exchange spans carry backend/role/wire bytes -- see
-  :mod:`repro.core.schedule`);
-- ``Plan.profile`` aggregates those spans into an observed-vs-predicted
-  per-stage table;
-- ``benchmarks/run.py --trace out.json`` merges per-section and
-  per-subprocess traces into one artifact (:func:`TraceRecorder.adopt`
-  re-homes foreign events under their own pid row).
+- one stage scope per schedule stage, ``repro.stage<i>.<Kind>``
+  (:func:`stage`, ``<i>`` the stage's index in ``Schedule.stages``);
+- inside it one layer scope where the work happens (:func:`layer`):
+  :data:`LOCAL_FFT`, :data:`EXCHANGE` (the collective call alone),
+  :data:`RELAYOUT` (local transposes, packs and unpacks) and
+  :data:`TWIDDLE`. Where layer scopes nest, the innermost names the op.
 
-Consumers: ``CommParams.refine_online`` (alpha/beta re-fit from observed
-exchange spans), ``planner.record_observed`` (wisdom observed-timings
-channel) and ``StepMonitor`` (straggler culprit attribution).
+Host spans (:func:`span`, over ``jax.profiler.TraceAnnotation``):
+:data:`EXECUTE` around ``Plan.execute`` / ``Plan.inverse``, carrying the
+plan's call number, and every :meth:`TraceRecorder.span`. With the
+profiler off a span costs one check.
 
-Timestamps come from an injectable monotonic clock (seconds); exports
-convert to the microseconds Chrome-trace expects. Span ``ts`` are
-relative to the recorder's creation, so merged traces from different
-processes line up per-pid rather than pretending to share a clock.
+:class:`TraceRecorder` also keeps its own list of :class:`Span` records
+(name + wall-clock start/duration + free-form ``args``) for the
+segmented executor (``run_schedule(..., trace=rec)``: one span per
+stage, Exchange spans carry backend/role/wire bytes) and ``Plan.profile``,
+and exports them as Chrome-trace JSON; ``benchmarks/run.py --trace``
+folds per-subprocess traces into one artifact
+(:meth:`TraceRecorder.adopt`). Its consumers: ``CommParams.refine_online``
+(alpha/beta re-fit from observed exchange spans),
+``planner.record_observed`` and ``StepMonitor``.
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
 import time
-from contextlib import contextmanager
-from typing import Any, Callable, Dict, Iterable, Iterator, List, Optional
+from typing import Any, Callable, ContextManager, Dict, Iterable, Iterator, List, Optional
+
+import jax
+
+#: the prefix of every name the program puts on the profiler's timeline
+PREFIX = "repro."
+#: host span around ``Plan.execute`` / ``Plan.inverse``
+EXECUTE = PREFIX + "execute"
+#: layer scopes of the device ops
+LOCAL_FFT = PREFIX + "local_fft"
+EXCHANGE = PREFIX + "exchange"
+RELAYOUT = PREFIX + "relayout"
+TWIDDLE = PREFIX + "twiddle"
+LAYERS = (LOCAL_FFT, EXCHANGE, RELAYOUT, TWIDDLE)
+
+_OFF = contextlib.nullcontext()
+
+
+def span(name: str, **args) -> ContextManager:
+    """A host span ``name`` on the profiler's timeline, with ``args`` as
+    its metadata; with the profiler off, one check and a shared no-op
+    context."""
+    if not jax.profiler.TraceAnnotation.is_enabled():
+        return _OFF
+    return jax.profiler.TraceAnnotation(name, **args)
+
+
+def layer(name: str) -> ContextManager:
+    """The layer scope ``name`` (one of :data:`LAYERS`) over the device
+    ops traced inside it."""
+    return jax.named_scope(name)
+
+
+def stage(index: int, st) -> ContextManager:
+    """The stage scope ``repro.stage<index>.<Kind>`` of schedule stage
+    ``st``."""
+    return jax.named_scope(f"{PREFIX}stage{index}.{type(st).__name__}")
 
 
 @dataclasses.dataclass
@@ -70,33 +107,14 @@ class Span:
         }
 
 
-@dataclasses.dataclass
-class CounterSample:
-    """One counter sample (Chrome-trace ``ph:"C"``): ``values`` maps
-    series name -> number, plotted as a stacked area per counter name."""
-
-    name: str
-    t: float
-    values: Dict[str, float]
-    pid: int = 0
-
-    def to_chrome(self) -> Dict[str, Any]:
-        return {
-            "name": self.name,
-            "ph": "C",
-            "ts": self.t * 1e6,
-            "pid": self.pid,
-            "tid": 0,
-            "args": dict(self.values),
-        }
-
-
 class TraceRecorder:
-    """Collects spans + counters; exports Chrome-trace JSON and JSONL.
+    """Collects spans; exports Chrome-trace JSON.
 
     The clock is injectable (tests pass a fake); production uses
     ``time.perf_counter``. Recording is append-only and cheap (one
-    dataclass per span) so it can stay on in serving paths.
+    dataclass per span) so it can stay on in serving paths. Each span
+    is also entered as the host span ``repro.<name>`` on the profiler's
+    timeline (:func:`span`).
     """
 
     def __init__(self, clock: Optional[Callable[[], float]] = None, *, pid: int = 0):
@@ -104,7 +122,6 @@ class TraceRecorder:
         self._epoch = self._clock()
         self.pid = pid
         self.spans: List[Span] = []
-        self.counters: List[CounterSample] = []
         self._process_names: Dict[int, str] = {}
         self._adopted: List[Dict[str, Any]] = []
 
@@ -113,14 +130,15 @@ class TraceRecorder:
         """Seconds since the recorder was created."""
         return self._clock() - self._epoch
 
-    @contextmanager
+    @contextlib.contextmanager
     def span(self, name: str, *, cat: str = "stage", tid: int = 0, **args) -> Iterator[Span]:
         """Context manager stamping one span around the enclosed work.
         Extra keyword arguments become the span's ``args``; the yielded
         span may be annotated further before the block exits."""
         sp = Span(name=name, t0=self.now(), dur=0.0, cat=cat, pid=self.pid, tid=tid, args=args)
         try:
-            yield sp
+            with span(PREFIX + name, cat=cat, **args):
+                yield sp
         finally:
             sp.dur = self.now() - sp.t0
             self.spans.append(sp)
@@ -141,11 +159,6 @@ class TraceRecorder:
         self.spans.append(sp)
         return sp
 
-    def counter(self, name: str, **values: float) -> CounterSample:
-        c = CounterSample(name=name, t=self.now(), values=dict(values), pid=self.pid)
-        self.counters.append(c)
-        return c
-
     # -- queries -----------------------------------------------------------
     def mark(self) -> int:
         """Bookmark for :meth:`spans_since` (e.g. per serve dispatch)."""
@@ -159,10 +172,7 @@ class TraceRecorder:
         ``CommParams.refine_online`` fits against."""
         return [s for s in self.spans if s.cat == "exchange"]
 
-    def total_seconds(self) -> float:
-        return sum(s.dur for s in self.spans)
-
-    # -- merging -----------------------------------------------------------
+    # -- adoption ----------------------------------------------------------
     def set_process_name(self, pid: int, name: str) -> None:
         self._process_names[pid] = name
 
@@ -189,16 +199,10 @@ class TraceRecorder:
         if name is not None:
             self.set_process_name(pid, name)
 
-    def merge(self, other: "TraceRecorder", *, pid: Optional[int] = None, name: Optional[str] = None) -> None:
-        self.adopt(other._chrome_events(), pid=pid, name=name)
-
     # -- exports -----------------------------------------------------------
-    def _chrome_events(self) -> List[Dict[str, Any]]:
-        return [s.to_chrome() for s in self.spans] + [c.to_chrome() for c in self.counters]
-
     def to_chrome_trace(self) -> Dict[str, Any]:
         """The ``chrome://tracing`` / Perfetto JSON object."""
-        events = self._chrome_events() + list(self._adopted)
+        events = [s.to_chrome() for s in self.spans] + list(self._adopted)
         for pid, pname in sorted(self._process_names.items()):
             events.append({
                 "name": "process_name",
@@ -212,55 +216,3 @@ class TraceRecorder:
     def write_chrome_trace(self, path: str) -> None:
         with open(path, "w") as f:
             json.dump(self.to_chrome_trace(), f, indent=1)
-
-    def to_jsonl(self) -> str:
-        """One JSON object per line: spans (``{"kind": "span", ...}``
-        with seconds-valued ``t0``/``dur``) then counters."""
-        lines = []
-        for s in self.spans:
-            lines.append(json.dumps({
-                "kind": "span", "name": s.name, "cat": s.cat, "t0": s.t0,
-                "dur": s.dur, "pid": s.pid, "tid": s.tid, "args": s.args,
-            }))
-        for c in self.counters:
-            lines.append(json.dumps({
-                "kind": "counter", "name": c.name, "t": c.t,
-                "pid": c.pid, "values": c.values,
-            }))
-        return "\n".join(lines) + ("\n" if lines else "")
-
-    def write_jsonl(self, path: str) -> None:
-        with open(path, "w") as f:
-            f.write(self.to_jsonl())
-
-    @classmethod
-    def from_jsonl(cls, path: str) -> "TraceRecorder":
-        rec = cls()
-        with open(path) as f:
-            for line in f:
-                line = line.strip()
-                if not line:
-                    continue
-                d = json.loads(line)
-                if d.get("kind") == "span":
-                    rec.spans.append(Span(
-                        name=d["name"], t0=d["t0"], dur=d["dur"],
-                        cat=d.get("cat", "stage"), pid=d.get("pid", 0),
-                        tid=d.get("tid", 0), args=d.get("args", {}),
-                    ))
-                elif d.get("kind") == "counter":
-                    rec.counters.append(CounterSample(
-                        name=d["name"], t=d["t"], values=d.get("values", {}),
-                        pid=d.get("pid", 0),
-                    ))
-        return rec
-
-
-def merge_traces(recorders: Iterable[TraceRecorder], names: Optional[Iterable[str]] = None) -> TraceRecorder:
-    """Merge recorders into a fresh one, one pid row each."""
-    out = TraceRecorder()
-    names = list(names) if names is not None else None
-    for i, rec in enumerate(recorders):
-        label = names[i] if names and i < len(names) else None
-        out.merge(rec, pid=i + 1, name=label)
-    return out
