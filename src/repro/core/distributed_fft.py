@@ -92,6 +92,7 @@ def _build(x: jax.Array, mesh: Mesh, axis_name: str, cfg: FFTConfig, *,
         axis_name=axis_name, p=mesh.shape[axis_name], backend=cfg.strategy,
         fused=_wants_fused(cfg), n_chunks=cfg.n_chunks,
         transpose_back=cfg.transpose_back, rows=rows,
+        transpose_first=sch.transposed_first_pays(x.shape, ndim, cfg.local_impl),
     )
 
 

@@ -798,6 +798,9 @@ class Plan:
                 fused=self.fused or self._cfg.fuse_dft,
                 n_chunks=self.n_chunks,
                 transpose_back=self.transpose_back, pad=self.pad,
+                transpose_first=sch.transposed_first_pays(
+                    self.global_shape, self.ndim, self.local_impl
+                ),
             )
         self._schedules[inv] = built
         return built
@@ -843,8 +846,10 @@ class Plan:
         ``wisdom-hit`` / ``observed-overlay``), the timing table the
         decision argmin'd over (measured seconds for a measured plan,
         alpha-beta model seconds otherwise), the wisdom key consulted,
-        and the calibration constants in force (with whether they are
-        fitted fabric constants or the module defaults). Rendered by
+        the calibration constants in force (with whether they are
+        fitted fabric constants or the module defaults), and whether the
+        planned schedule is the one-shard transposed-first form
+        (``transposed_first``). Rendered by
         :meth:`why_text`; dumped by ``benchmarks/run.py --explain``;
         aggregated as gauges in serve ``metrics()``."""
         from repro.core import planner as _planner
@@ -871,6 +876,7 @@ class Plan:
             "planner": self.planner,
             "fused": self.fused,
             "n_chunks": self.n_chunks,
+            "transposed_first": self.schedule().transposed_first,
             "timings_kind": timings_kind,
             "timings": timings,
             "argmin": argmin,

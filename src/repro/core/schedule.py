@@ -419,6 +419,13 @@ class Schedule:
         return hashlib.sha256(self.canonical().encode()).hexdigest()[:12]
 
     # -- queries -----------------------------------------------------------
+    @property
+    def transposed_first(self) -> bool:
+        """Whether this 2-D c2c slab schedule transposes before both FFT
+        passes (its one-shard form, with no exchange)."""
+        return self.kind == "fft2" and self.decomp == "slab" and (
+            self.stages[:1] == (Relayout("swap_last2"),))
+
     def exchanges(self, role: Optional[str] = None) -> Tuple[Exchange, ...]:
         return tuple(
             st for st in self.stages
@@ -459,10 +466,10 @@ def schedule_comm_bytes(sched: Schedule, real_itemsize: int, complex_itemsize: i
     """Total bytes each device ships per transform -- the sum of every
     Exchange stage's wire payload. ``Plan.comm_bytes`` and the HLO-parser
     cross-checks both consume this walk."""
-    return sum(
+    return sum((
         exchange_wire_bytes(st, real_itemsize, complex_itemsize)
         for st in sched.exchanges()
-    )
+    ), 0.0)
 
 
 def stage_seconds(
@@ -496,10 +503,10 @@ def predict_seconds(
     :func:`stage_seconds` over its Exchange stages. ``Plan.predict`` is
     this walk over backend/pipeline rewrites of the plan's own schedule,
     so prediction and execution cannot drift."""
-    return sum(
+    return sum((
         stage_seconds(st, params, chunk_compute_s, real_itemsize, complex_itemsize)
         for st in sched.exchanges(role)
-    )
+    ), 0.0)
 
 
 # ---------------------------------------------------------------------------
@@ -586,6 +593,7 @@ def build_schedule(
     transpose_back: bool = False,
     pad: bool = True,
     rows: Optional[int] = None,
+    transpose_first: bool = False,
 ) -> Schedule:
     """Lower one distributed transform to its stage schedule.
 
@@ -594,7 +602,9 @@ def build_schedule(
     for slab the mesh axis and its size. Real problems are validated
     here (the builder needs ``h``/``hp`` anyway); slab c2c divisibility
     stays with the plan layer so direct entry-point callers keep the
-    transpose-level errors they always had."""
+    transpose-level errors they always had. ``transpose_first`` lowers a
+    one-shard 2-D c2c slab schedule transposed first (see
+    :func:`_slab_c2c` and :func:`transposed_first_pays`)."""
     shape = tuple(global_shape)
     if decomp == "pencil":
         if real:
@@ -613,8 +623,34 @@ def build_schedule(
         )
     return _slab_c2c(
         shape, ndim, inverse, axis_name, p, backend, fused, n_chunks,
-        transpose_back, rows,
+        transpose_back, rows, transpose_first,
     )
+
+
+#: the first-pass lengths at which XLA's TPU FFT takes a transposed input
+#: for free: multiples of two full 128-point levels
+TRANSPOSED_FIRST_LEN = 128 * 128
+
+
+def transposed_first_pays(global_shape, ndim: int, impl: str) -> bool:
+    """Whether a one-shard 2-D c2c slab transform should run transposed
+    first (``build_schedule(transpose_first=...)``). With the Pallas
+    local FFT it does for an unbatched input: the transpose merges with
+    the moves around the kernels (0-9% faster on a TPU v5e, 1024^2 to
+    16384^2, square or not, both directions), where a batch of four
+    4096^2 ran 2.5% slower. With XLA's own FFT (``"jnp"``) it does where
+    the first pass, along the input's axis -2, has a length that is a
+    multiple of :data:`TRANSPOSED_FIRST_LEN`: XLA then takes the split
+    planes through a bitcast and writes the result row-major, three
+    full-array copies fewer (8-9% faster at 16384^2 and 16384x8192); at
+    other lengths XLA adds transposing copies of its own, and the
+    inverse ran up to 7% slower. The matmul FFT, faster or slower by
+    size, keeps the exchange form."""
+    if ndim != 2:
+        return False
+    if impl == "pallas":
+        return len(global_shape) == 2
+    return impl == "jnp" and global_shape[-2] % TRANSPOSED_FIRST_LEN == 0
 
 
 def _global_kind(backend: str) -> Optional[str]:
@@ -627,7 +663,7 @@ def _global_kind(backend: str) -> Optional[str]:
     return backend if b.kind == "global" else None
 
 
-def _slab_c2c(shape, ndim, inverse, ax, p, backend, fused, n_chunks, tb, rows):
+def _slab_c2c(shape, ndim, inverse, ax, p, backend, fused, n_chunks, tb, rows, tf):
     gb = _global_kind(backend)
     m = float(np.prod(shape)) / p
 
@@ -643,11 +679,18 @@ def _slab_c2c(shape, ndim, inverse, ax, p, backend, fused, n_chunks, tb, rows):
     )
     if ndim == 2:
         stages = [LocalFFT(axis=-1), ex(fft=True, fuse=fused)]
+        out_tail = (ax, None)
         if tb:
             stages.append(ex())
+        elif tf and p == 1 and gb is None:
+            # One shard: the exchange moves no data, so the passes may run
+            # in either order around the transpose (both give F X^T F =
+            # Y^T); transposed first where transposed_first_pays says so.
+            stages = [Relayout("swap_last2"), LocalFFT(axis=-1), LocalFFT(axis=-2)]
+            out_tail = (None, ax)
         return Schedule(
             kind="fft2", stages=tuple(stages), in_tail=(ax, None),
-            out_tail=(ax, None), conj=inverse,
+            out_tail=out_tail, conj=inverse,
             scale=float(shape[-1] * shape[-2]) if inverse else None, **meta,
         )
     if ndim == 3:
@@ -1175,9 +1218,10 @@ def run_schedule(
 
     With ``faults`` (an *armed* :class:`repro.runtime.faults.FaultPlan`)
     the schedule also executes segmented, consulting the fault plan
-    before every Exchange segment (and before a ``global:`` reference
-    dispatch) so a matching spec can raise, stall, or report device loss
-    at exactly the stage it names -- deterministic chaos on the IR. An
+    before every Exchange segment, the local pass that replaces the
+    one-shard exchange, and a ``global:`` reference dispatch, so a
+    matching spec can raise, stall, or report device loss at exactly
+    the stage it names -- deterministic chaos on the IR. An
     exhausted (``active() == False``) or absent fault plan costs
     nothing: the fast path runs unchanged."""
     if faults is not None and faults.active():
@@ -1212,7 +1256,8 @@ def _run_schedule_faulted(
 ) -> jax.Array:
     """Chaos-mode executor: the trace-mode segment walk without spans or
     fences, calling ``faults.on_stage(label, index=...)`` before every
-    Exchange segment (Twiddles ride their Exchange, as in tracing).
+    fault point (:func:`_fault_label`; Twiddles ride their Exchange, as
+    in tracing).
     Injected faults therefore surface as *host* exceptions at dispatch
     time -- synchronously and deterministically -- while the segments
     themselves still launch async; numerics of a non-firing run match
@@ -1224,9 +1269,9 @@ def _run_schedule_faulted(
     bounds = simulate_specs(sched, x.ndim)
     v = jnp.conj(x) if sched.conj else x
     for start, seg in _segments(sched):
-        report = seg[-1]
-        if isinstance(report, Exchange):
-            faults.on_stage(_stage_label(report), index=start + len(seg) - 1)
+        label = _fault_label(sched, start, seg)
+        if label is not None:
+            faults.on_stage(label, index=start + len(seg) - 1)
         fn = _segment_executable(
             sched, start, len(seg), impl, mesh,
             P(*bounds[start]), P(*bounds[start + len(seg)]),
@@ -1237,6 +1282,19 @@ def _run_schedule_faulted(
     if sched.scale is not None:
         v = v / sched.scale
     return v
+
+
+def _fault_label(sched: Schedule, start: int, seg) -> Optional[str]:
+    """The label the chaos hook is consulted with before a segment, or
+    None where the segment is no fault point. Every Exchange is one; so
+    is the second pass of a transposed-first schedule, which stands
+    where the one-shard exchange stood, so chaos aimed at ``Exchange``
+    still fires on one device."""
+    if isinstance(seg[-1], Exchange):
+        return _stage_label(seg[-1])
+    if sched.transposed_first and start == len(sched.stages) - 1:
+        return f"Exchange(slab:{sched.in_tail[0]}, p=1, fft, local)"
+    return None
 
 
 def _segments(sched: Schedule) -> Tuple[Tuple[int, Tuple[object, ...]], ...]:
@@ -1345,10 +1403,11 @@ def _run_schedule_traced(
             cat = "stage"
             args = {"stage": type(report).__name__}
         args["index"] = start + len(seg) - 1
-        if faults is not None and isinstance(report, Exchange):
+        label = None if faults is None else _fault_label(sched, start, seg)
+        if label is not None:
             # consult the chaos hook OUTSIDE the span: an injected raise
             # must not leave a half-open span in the recorder
-            faults.on_stage(_stage_label(report), index=start + len(seg) - 1)
+            faults.on_stage(label, index=start + len(seg) - 1)
         fn = _segment_executable(sched, start, len(seg), impl, mesh, in_spec, out_spec)
         with trace.span(_stage_label(report), cat=cat, **args):
             v = fn(v)

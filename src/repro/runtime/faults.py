@@ -8,8 +8,9 @@ Exchange succeeds. This module supplies the failure *contract*:
 - :class:`FaultPlan` -- a seeded, fully deterministic chaos hook
   installed via ``run_schedule(..., faults=)`` (and through
   ``Plan.faults`` / ``SpectralEngine(faults=)`` / the train driver).
-  The executor consults it before every Exchange segment (and before a
-  ``global:`` reference dispatch); a matching spec can **raise**
+  The executor consults it before every Exchange segment (and before
+  the local pass that replaces a one-shard exchange, and a ``global:``
+  reference dispatch); a matching spec can **raise**
   (:class:`InjectedFault`), **stall** past a deadline (injectable
   ``sleep``), or report **device loss** (:class:`DeviceLossFault`
   carrying the surviving device count -- the signal
@@ -85,7 +86,8 @@ class FaultSpec:
 class FaultPlan:
     """A deterministic, seeded set of :class:`FaultSpec`\\ s.
 
-    The executor calls :meth:`on_stage` with each Exchange's label just
+    The executor calls :meth:`on_stage` with each Exchange's label (or
+    the label of the exchange a one-shard schedule folded away) just
     before launching the segment; the plan counts matches per spec and
     applies whichever armed spec is scheduled to fire -- raising,
     sleeping (``sleep`` is injectable), or raising device loss. Every

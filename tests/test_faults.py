@@ -73,11 +73,12 @@ def _fresh_wisdom():
 
 
 def _x(n=16, seed=0, batch=None):
+    return _rect((n, n) if batch is None else (batch, n, n), seed)
+
+
+def _rect(shape, seed=0):
     rng = np.random.default_rng(seed)
-    shape = (n, n) if batch is None else (batch, n, n)
-    return (rng.standard_normal(shape) + 1j * rng.standard_normal(shape)).astype(
-        np.complex64
-    )
+    return (rng.standard_normal(shape) + 1j * rng.standard_normal(shape)).astype(np.complex64)
 
 
 def _want(x):
@@ -199,6 +200,27 @@ class TestFaultPlan:
         assert all(s.dur >= 0.0 for s in rec.spans)
         assert not any(s.cat == "exchange" for s in rec.spans)
 
+    @pytest.mark.parametrize("shape,transpose_back,first", [
+        ((16384, 8), False, True),
+        ((16384, 8), True, False),
+        ((16, 16), False, False),
+    ])
+    def test_one_device_exchange_point_fires(self, mesh1, shape, transpose_back, first):
+        # transposed first, the one-shard schedule has no Exchange stage:
+        # its second pass stands where the exchange stood and is the
+        # fault point; otherwise the one-shard exchange still is
+        fp = FaultPlan.error(match="Exchange(slab:model")
+        plan = plan_fft(shape, mesh1, faults=fp, transpose_back=transpose_back)
+        assert plan.schedule().transposed_first is first
+        x = _rect(shape)
+        with pytest.raises(InjectedFault, match="p=1"):
+            plan.execute(jnp.asarray(x))
+        assert [e["index"] for e in fp.events] == [2 if first else 1]
+        want = np.fft.fft2(x) if transpose_back else _want(x)
+        np.testing.assert_allclose(
+            np.asarray(plan.execute(jnp.asarray(x))), want, rtol=1e-4, atol=1e-4
+        )
+
     def test_bad_specs_rejected(self):
         with pytest.raises(ValueError, match="mode"):
             FaultSpec("explode")
@@ -253,6 +275,21 @@ class TestServeIsolation:
         np.testing.assert_allclose(np.asarray(fut.result()), _want(x),
                                    rtol=1e-5, atol=1e-6)
         assert eng.retries == 1 and eng.quarantined == 0 and eng.errors == 1
+
+    @pytest.mark.parametrize("transpose_back", [False, True])
+    def test_retry_heals_transient_fault_transposed_first(self, mesh1, transpose_back):
+        # the paper's row length: one shard runs transposed first unless
+        # transpose_back, and an Exchange fault fires either way
+        eng = SpectralEngine(mesh1, max_batch=1, retry=RetryPolicy(max_retries=1),
+                             plan_kwargs={"transpose_back": transpose_back})
+        x = _rect((16384, 8))
+        eng.submit("fft", x).block()
+        eng.set_faults(FaultPlan.error(match="Exchange", times=1))
+        fut = eng.submit("fft", x)
+        eng.drain()
+        want = np.fft.fft2(x) if transpose_back else _want(x)
+        np.testing.assert_allclose(np.asarray(fut.result()), want, rtol=1e-4, atol=1e-4)
+        assert eng.retries == 1 and eng.errors == 1
 
     def test_retry_deadline_abandons(self, mesh1):
         # every clock read advances 1s -> the 0.5s budget is already

@@ -81,7 +81,25 @@ def snapshot_cases():
         global_shape=(16, 16), ndim=2, inverse=False, real=True,
         decomp="slab", axis_name="x", p=4, backend="xla_auto",
     )
+    # one shard: asked to, the 2-D c2c slab transform is transposed
+    # first; unasked, and in the transpose_back, r2c and GSPMD forms, it
+    # keeps its P>1 structure
+    for key, kw in P1_CASES.items():
+        cases[f"slab/ndim2/{key}/p1"] = dict(
+            global_shape=(16, 16), ndim=2, decomp="slab", axis_name="x", p=1, **kw
+        )
     return cases
+
+
+#: the one-shard pipelines of the snapshot grid (key -> build kwargs)
+P1_CASES = {
+    "c2c/fwd": dict(backend="alltoall"),
+    "c2c/fwd/tf": dict(backend="alltoall", transpose_first=True),
+    "c2c/inv/tf": dict(backend="alltoall", inverse=True, transpose_first=True),
+    "c2c/fwd/tb/tf": dict(backend="alltoall", transpose_back=True, transpose_first=True),
+    "r2c/fwd/tf": dict(backend="alltoall", real=True, transpose_first=True),
+    "c2c/fwd/xla_auto/tf": dict(backend="xla_auto", transpose_first=True),
+}
 
 
 def build_snapshots():
@@ -282,6 +300,104 @@ def test_describe_renders_stage_table():
     assert s.schedule_hash() in text
     assert "LocalFFT" in text and "Exchange" in text
     assert "wire bytes" in text and "total modeled exchange time" in text
+
+
+TRANSPOSED_FIRST = (
+    sch.Relayout("swap_last2"), sch.LocalFFT(axis=-1), sch.LocalFFT(axis=-2),
+)
+
+
+@pytest.mark.parametrize("inverse", [False, True], ids=["fwd", "inv"])
+@pytest.mark.parametrize("p", [1, 4])
+def test_slab_fft2_stage_order(p, inverse):
+    """One shard, asked to: transpose, then both passes local (the
+    exchange would move no data). More shards: first pass, then the
+    exchange that carries the second."""
+    s = sch.build_schedule((16, 16), ndim=2, inverse=inverse, decomp="slab",
+                           axis_name="x", p=p, backend="alltoall", transpose_first=True)
+    assert s.conj == inverse and s.scale == (256.0 if inverse else None)
+    if p == 1:
+        assert s.stages == TRANSPOSED_FIRST and s.transposed_first
+        assert s.exchanges() == () and s.out_tail == (None, "x")
+        assert "Relayout(swap_last2)" in s.describe()
+    else:
+        assert [type(st) for st in s.stages] == [sch.LocalFFT, sch.Exchange]
+        assert s.stages[1].fft and s.stages[1].p == 4 and s.out_tail == ("x", None)
+        assert not s.transposed_first and "Relayout" not in s.describe()
+    assert sch.simulate_specs(s, 2)[-1] == s.out_tail
+
+
+@pytest.mark.parametrize("shape", [(32, 32), (32, 48), (3, 32, 48)],
+                         ids=["square", "wide", "batched"])
+@pytest.mark.parametrize("direction", ["forward", "inverse"])
+def test_one_shard_fft2_matches_numpy(shape, direction):
+    """The transposed-first schedule gives the transposed spectrum
+    (forward) and the transposed inverse at the distributed tests'
+    tolerance."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.core.compat import make_mesh
+
+    inverse = direction == "inverse"
+    s = sch.build_schedule(shape, ndim=2, inverse=inverse, decomp="slab", axis_name="model",
+                           p=1, backend="alltoall", transpose_first=True)
+    assert s.stages == TRANSPOSED_FIRST
+    rng = np.random.default_rng(len(shape) * 100 + shape[-1])
+    x = (rng.standard_normal(shape) + 1j * rng.standard_normal(shape)).astype(np.complex64)
+    ref = np.swapaxes(np.fft.ifft2(x) if inverse else np.fft.fft2(x), -1, -2)
+    y = np.asarray(sch.run_schedule(jnp.asarray(x), s, make_mesh((1,), ("model",))))
+    assert y.shape == ref.shape
+    assert np.abs(y - ref).max() < 1e-4 * np.abs(ref).max()
+
+
+@pytest.mark.parametrize("shape,impl,first", [
+    ((16384, 8), "jnp", True),
+    ((32768, 8), "jnp", True),
+    ((8, 16384), "jnp", False),
+    ((4096, 8), "jnp", False),
+    ((64, 64), "jnp", False),
+    ((64, 64), "pallas", True),
+    ((2, 64, 64), "pallas", False),
+    ((16384, 8), "matmul", False),
+])
+def test_one_shard_plan_transposes_first_where_it_pays(shape, impl, first):
+    """A plan runs transposed first with the Pallas FFT on an unbatched
+    input, and with XLA's FFT where the first-pass length is a multiple
+    of 128 * 128; elsewhere it keeps the exchange, and says which in
+    why() and its stage table."""
+    from repro.core import plan_fft
+    from repro.core.compat import make_mesh
+
+    plan = plan_fft(shape, make_mesh((1,), ("model",)), ndim=2, backend="alltoall",
+                    local_impl=impl)
+    assert plan.why()["transposed_first"] is first
+    assert ("Relayout(swap_last2)" in plan.describe()) is first
+    assert (plan.schedule().stages == TRANSPOSED_FIRST) is first
+    assert (plan.schedule(inverse=True).stages == TRANSPOSED_FIRST) is first
+
+
+def test_one_shard_plan_says_transposed_first():
+    """A transposed-first plan says so, computes the transposed spectrum
+    and round-trips, and prices no exchange."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.core import plan_fft
+    from repro.core.compat import make_mesh
+
+    plan = plan_fft((16384, 8), make_mesh((1,), ("model",)), ndim=2, backend="alltoall")
+    assert plan.why()["transposed_first"]
+    assert "Relayout(swap_last2)" in plan.describe()
+    rng = np.random.default_rng(7)
+    x = (rng.standard_normal((16384, 8)) + 1j * rng.standard_normal((16384, 8))).astype(np.complex64)
+    ref = np.swapaxes(np.fft.fft2(x), -1, -2)
+    y = np.asarray(plan.execute(jnp.asarray(x)))
+    assert np.abs(y - ref).max() < 1e-4 * np.abs(ref).max()
+    z = np.asarray(plan.inverse(jnp.asarray(y)))
+    assert np.abs(z - x).max() < 1e-4 * np.abs(x).max()
+    assert plan.predict() == dict.fromkeys(plan.predict(), 0.0)
+    assert plan.comm_bytes() == 0.0
 
 
 def test_plan_level_invariants_8dev():

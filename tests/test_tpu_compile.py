@@ -21,6 +21,8 @@ jnp = jax.numpy
 from jax.sharding import Mesh, SingleDeviceSharding  # noqa: E402
 
 from repro.core import plan_fft  # noqa: E402
+from repro.core.comm_model import shape_bytes  # noqa: E402
+from repro.core.hlo_analysis import parse_hlo  # noqa: E402
 from repro.kernels import fft_stage  # noqa: E402
 
 #: v5e HBM per chip
@@ -120,3 +122,20 @@ def test_fft2_16384_forward_compiles_for_one_chip(topo, one_chip):
     plan = plan_fft((N, N), mesh, ndim=2, backend="alltoall")
     compiled = _compile(jax.jit(plan.execute), plan.input_spec())
     _check(compiled, 0)
+    # the one-shard schedule is transposed first, so XLA's FFT expansion
+    # takes the argument's planes as they are split and its combine
+    # writes the row-major result: no retile copy of a plane, no copy of
+    # the result
+    comps, entry = parse_hlo(compiled.as_text())
+    ops = {op.name: op for op in comps[entry].ops}
+    (root,) = [op for op in ops.values() if op.raw.startswith("ROOT")]
+    assert root.kind == "custom-call" and 'custom_call_target="X64Combine"' in root.raw, root.raw
+    splits = {op.name for op in ops.values() if 'custom_call_target="X64Split' in op.raw}
+    retiled = {op.name for op in ops.values()
+               if op.kind == "bitcast" and ",8,128,128]" in op.result_type
+               and splits.intersection(op.operands)}
+    assert not [op.name for op in ops.values()
+                if op.kind == "copy" and retiled.intersection(op.operands)]
+    big_copies = [op.name for op in ops.values()
+                  if op.kind == "copy" and shape_bytes(op.result_type) >= 2**30]
+    assert len(big_copies) <= 11, big_copies
