@@ -13,14 +13,17 @@ the plan produces.
 - :mod:`repro.apps.convolve` -- distributed circular convolution/correlation
 - :mod:`repro.apps.derivatives` -- spectral gradient / laplacian
 - :mod:`repro.apps.spectral` -- shared wavenumber-grid plumbing
+- :mod:`repro.apps.npb_ft` -- NAS Parallel Benchmarks FT (evolve, checksum)
 """
 
 from repro.apps.convolve import fft_convolve, fft_correlate
 from repro.apps.derivatives import gradient, laplacian
+from repro.apps.npb_ft import NPBFT
 from repro.apps.poisson import solve_poisson
 from repro.apps.spectral import plan_directions, wavenumbers
 
 __all__ = [
+    "NPBFT",
     "fft_convolve",
     "fft_correlate",
     "gradient",

@@ -1006,7 +1006,12 @@ class Plan:
     def _fn(self, inverse: bool):
         built = self.schedule(inverse)  # ndim=1 inverse raises here
         mesh, impl = self.mesh, self.local_impl
-        return lambda x: sch.run_schedule(x, built, mesh, impl=impl)
+        run = lambda x: sch.run_schedule(x, built, mesh, impl=impl)  # noqa: E731
+        if inverse:
+            # a module name of its own, so that a trace reduction keyed by
+            # module never reads the forward's instructions for these
+            run.__name__ = run.__qualname__ = "inverse"
+        return run
 
     def _executable(self, inverse: bool, dtype) -> jax.stages.Wrapped:
         key = ("inverse" if inverse else "forward", jnp.dtype(dtype).name)

@@ -16,7 +16,9 @@ nothing at run time):
 - inside it one layer scope where the work happens (:func:`layer`):
   :data:`LOCAL_FFT`, :data:`EXCHANGE` (the collective call alone),
   :data:`RELAYOUT` (local transposes, packs and unpacks) and
-  :data:`TWIDDLE`. Where layer scopes nest, the innermost names the op.
+  :data:`TWIDDLE`. Where layer scopes nest, the innermost names the op;
+- the layer scopes of an app's own jitted steps between transforms:
+  :data:`EVOLVE` and :data:`CHECKSUM` (NPB FT, ``repro.apps.npb_ft``).
 
 Host spans (:func:`span`, over ``jax.profiler.TraceAnnotation``):
 :data:`EXECUTE` around ``Plan.execute`` / ``Plan.inverse``, carrying the
@@ -53,7 +55,10 @@ LOCAL_FFT = PREFIX + "local_fft"
 EXCHANGE = PREFIX + "exchange"
 RELAYOUT = PREFIX + "relayout"
 TWIDDLE = PREFIX + "twiddle"
-LAYERS = (LOCAL_FFT, EXCHANGE, RELAYOUT, TWIDDLE)
+#: layer scopes of NPB FT's own steps (``repro.apps.npb_ft``)
+EVOLVE = PREFIX + "evolve"
+CHECKSUM = PREFIX + "checksum"
+LAYERS = (LOCAL_FFT, EXCHANGE, RELAYOUT, TWIDDLE, EVOLVE, CHECKSUM)
 
 _OFF = contextlib.nullcontext()
 

@@ -7,9 +7,12 @@ the ``repro.execute`` host span of ``Plan.execute`` / ``Plan.inverse``.
 The compiles run in one 4-device subprocess at 64^2 (slab ``alltoall``
 at P=4 and P=1, the fused ``scatter`` pipeline, the six-step 1-D
 transform whose Twiddle rides a streaming Exchange), each plan compiled
-twice: as written, and with ``jax.named_scope`` patched to a no-op.
+twice: as written, and with ``jax.named_scope`` patched to a no-op. The
+same subprocess compiles NPB FT's own steps (``repro.apps.npb_ft``:
+evolve and checksum) on a P=1 slab and a 2x2 pencil at 16x32x64.
 """
 
+import hashlib
 import json
 import os
 import re
@@ -58,6 +61,19 @@ for name, (p, kw) in CASES.items():
     finally:
         jax.named_scope = real
     out[name] = {"stages": kinds, "scoped": scoped, "plain": plain}
+
+from repro.apps.npb_ft import NPBFT
+
+APP_MESHES = {
+    "npb_slab_p1": (make_mesh((1,), ("model",)), "slab"),
+    "npb_pencil_2x2": (make_mesh((2, 2), ("rows", "cols")), "pencil"),
+}
+for name, (mesh, decomp) in APP_MESHES.items():
+    plan = plan_fft((16, 32, 64), mesh, ndim=3, decomp=decomp, backend="alltoall",
+                    dtype=jnp.complex64)
+    app = NPBFT(plan)
+    out[name] = {step: LOCATIONS.sub("", low.compile().as_text())
+                 for step, low in app.lower().items()}
 print("RESULT " + json.dumps(out))
 """
 
@@ -66,8 +82,18 @@ _INSTR = re.compile(r"^\s*(?:ROOT )?%([\w.-]+) = .*? ([a-z][a-z0-9_-]*)\(")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _TARGET = re.compile(r'custom_call_target="([^"]*)"')
 _METADATA = re.compile(r", metadata=\{[^}]*\}")
-_LAYER = re.compile(r"(?:^|/)repro\.(local_fft|exchange|relayout|twiddle)(?=/|$)")
+_LAYER = re.compile(
+    r"(?:^|/)repro\.(local_fft|exchange|relayout|twiddle|evolve|checksum)(?=/|$)")
 _STAGE = re.compile(r"(?:^|/)repro\.stage(\d+)\.(\w+)(?=/|$)")
+APP_CASES = ("npb_slab_p1", "npb_pencil_2x2")
+#: sha256 of the paper plans' compiled HLO on this CPU backend (the 64^2
+#: stand-ins of ``paper_fft2_16k_p1``/``_p4``) once metadata is stripped
+#: and names renumbered (:func:`canonical`): a change that moves them
+#: changes what the paper cells run, and says so here
+PAPER_PLAN_DIGESTS = {
+    "slab_alltoall_p1": "fe748e9d0bdd02d89b66d9c359a985fd2c752ddede37ee7b8549d89165629f8e",
+    "slab_alltoall_p4": "fe89e15e6b7cbab63a038feae1921b2c669173ec2a8d8273308b6827f22b2492",
+}
 #: instructions outside every scope: the argument and what XLA hoists out
 #: of the shard_map body (constants, their broadcasts, bitcasts)
 BOUNDARY_OPCODES = ("parameter", "constant", "bitcast")
@@ -160,6 +186,34 @@ def test_op_class_unchanged_by_scopes(compiled, case):
             n2, op2, p2 or "", t2), (n1, p1, p2)
 
 
+@pytest.mark.parametrize("case", APP_CASES)
+@pytest.mark.parametrize("step", ["evolve", "checksum"])
+def test_app_ops_have_one_layer_scope(compiled, case, step):
+    """Every op of NPB FT's evolve and checksum lies under exactly its own
+    layer scope, and the op-path classifier reads it as relayout (the
+    checksum's psum as the exchange)."""
+    seen = 0
+    for name, opcode, path, target, line in instructions(compiled[case][step]):
+        if path is None:
+            continue
+        layers = _LAYER.findall(path)
+        if not layers:
+            assert boundary(opcode, line), (name, opcode, path)
+            continue
+        assert layers == [step], (name, path)
+        # the checksum's psum is a collective, and counts as one
+        want = "exchange" if opcode.startswith("all-reduce") else "relayout"
+        assert trace_reduce.op_class(name, opcode, path, target) == want, (name, path)
+        seen += 1
+    assert seen
+
+
+@pytest.mark.parametrize("case", sorted(PAPER_PLAN_DIGESTS))
+def test_paper_plans_hlo_unchanged(compiled, case):
+    text = canonical(compiled[case]["plain"])
+    assert hashlib.sha256(text.encode()).hexdigest() == PAPER_PLAN_DIGESTS[case]
+
+
 def test_scope_names_avoid_the_op_path_patterns():
     stage_names = [f"{obs.PREFIX}stage{i}.{k}" for i, k in enumerate(
         ("LocalFFT", "LocalR2C", "LocalC2R", "HermitianPack", "Trim", "Relayout",
@@ -168,6 +222,9 @@ def test_scope_names_avoid_the_op_path_patterns():
         assert name.startswith(obs.PREFIX)
         path = f"jit(<lambda>)/shard_map/{name}/transpose"
         assert trace_reduce.op_class("copy.1", "copy", path) == "relayout", name
+    for jitted, name in (("evolve", obs.EVOLVE), ("checksum", obs.CHECKSUM)):
+        path = f"jit({jitted})/{name}/mul"
+        assert trace_reduce.op_class("fusion.1", "fusion", path) == "relayout", name
 
 
 def test_only_obs_trace_names_spans_and_scopes():
@@ -179,7 +236,9 @@ def test_only_obs_trace_names_spans_and_scopes():
             if f.endswith(".py") and path != obs.__file__:
                 with open(path) as fh:
                     text = fh.read()
-                if "TraceAnnotation" in text or "named_scope" in text:
+                quoted = [f'"{n}"' for n in obs.LAYERS] + [f"'{n}'" for n in obs.LAYERS]
+                if ("TraceAnnotation" in text or "named_scope" in text
+                        or any(q in text for q in quoted)):
                     found.append(os.path.relpath(path, src))
     assert found == []
 

@@ -139,3 +139,21 @@ def test_fft2_16384_forward_compiles_for_one_chip(topo, one_chip):
     big_copies = [op.name for op in ops.values()
                   if op.kind == "copy" and shape_bytes(op.result_type) >= 2**30]
     assert len(big_copies) <= 11, big_copies
+
+
+def test_npb_ft_512_steps_compile_for_one_chip(topo, one_chip):
+    # NPB FT class C at one chip: the 512^3 forward and inverse of its plan
+    # and its evolve, each within the chip's HBM
+    from repro.apps import npb_ft
+
+    mesh = Mesh(np.array(topo.devices[:1]), ("model",))
+    plan = plan_fft((512,) * 3, mesh, ndim=3, decomp="slab", backend="alltoall",
+                    local_impl="jnp", dtype=jnp.complex64)
+    _check(_compile(jax.jit(plan.execute), plan.input_spec()), 0)
+    spectrum = plan.input_spec(opposite=True)
+    _check(_compile(jax.jit(plan.inverse), spectrum), 0)
+    tau = jax.ShapeDtypeStruct(spectrum.shape, jnp.float32, sharding=spectrum.sharding)
+    evolve = _compile(jax.jit(npb_ft.evolve), spectrum, tau)
+    _check(evolve, 0)
+    ma = evolve.memory_analysis()
+    assert ma.temp_size_in_bytes <= 2**30 + 2**20, ma.temp_size_in_bytes
