@@ -5,6 +5,10 @@ last field at P=1 slab, on a cube and on a grid whose three sides differ
 large enough that evolve moves every mode. A 4-host-device subprocess
 runs the same app on a P=4 slab and a 2x2 pencil, whose spectrum layout
 is axis-reversed: the checksums agree with the reference and each other.
+The fused iteration: one complex64 entry per run, one compiled
+specialization per program, the state carried as donated float32 planes
+(their axes in the app's order, the compiler's or one forced) that equal
+the unfused complex64 evolve.
 """
 
 import numpy as np
@@ -59,12 +63,74 @@ def test_tau_is_float32_exact(alpha):
     to 5e-6)."""
     shape = (16, 32, 64)
     plan = _slab_p1(shape)
-    tau = np.asarray(NPBFT(plan, alpha=alpha).tau)
+    app = NPBFT(plan, alpha=alpha)
+    tau = np.transpose(np.asarray(app.tau), np.argsort(app.order))
     fz, fy, fx = decay_factors(plan, alpha)
     assert tau.dtype == np.float32 and tau.shape == shape
     np.testing.assert_array_equal(tau, fz * fy * fx)
     exact = ref.twiddle(shape, alpha)
     assert np.max(np.abs(tau - exact) / exact) < 5 * 2.0**-24
+
+
+@pytest.mark.parametrize("shape", [(32, 32, 32), (16, 32, 64)])
+def test_run_enters_once_and_compiles_once(shape):
+    """A run of 20 iterations turns the forward's output into planes once
+    and calls the fused iteration 20 times; each program holds one
+    compiled specialization, so no later step can compile."""
+    plan = _slab_p1(shape)
+    app = NPBFT(plan)
+    app.run(jax.device_put(_field(shape), plan.input_sharding()), NITER)
+    assert (app.entries, app.iterations) == (1, NITER)
+    assert app._to_planes._cache_size() == 1 and app._iterate._cache_size() == 1
+
+
+@pytest.mark.parametrize("order", [None, (2, 0, 1)])
+@pytest.mark.parametrize("shape", [(32, 32, 32), (16, 32, 64)])
+def test_state_is_carried_as_float32_planes(shape, order):
+    """The state an iteration returns is two float32 arrays, the
+    spectrum's axes in the app's order (the compiler's, or one forced), in
+    the spectrum's sharding so ordered and in the default layout, equal to
+    the unfused complex64 ``u_hat * tau`` (a zero may differ in sign); the
+    planes an iteration is given are donated, where the backend honours
+    donation."""
+    plan = _slab_p1(shape)
+    app = NPBFT(plan, alpha=1e-3)
+    if order is not None:
+        app.order = order
+    u_hat = plan.execute(jax.device_put(_field(shape), plan.input_sharding()))
+    (re, im), _, _ = app.iterate(u_hat)
+    back = tuple(np.argsort(app.order))
+    tau = jnp.transpose(app.tau, back)
+    want = np.transpose(np.asarray(u_hat * tau), app.order)
+    spec = tuple(app.spectrum.spec) + (None,) * (3 - len(app.spectrum.spec))
+    assert app.carried.spec == tuple(spec[a] for a in app.order)
+    for plane, part in zip((re, im), (want.real, want.imag)):
+        assert plane.dtype == jnp.float32 and plane.shape == part.shape
+        assert plane.sharding.is_equivalent_to(app.carried, plane.ndim)
+        assert plane.format.layout.major_to_minor == (0, 1, 2)
+        np.testing.assert_array_equal(np.asarray(plane), part)
+    assert not u_hat.is_deleted()  # a complex64 state is never donated
+    (re2, _), _, _ = app.iterate((re, im))
+    np.testing.assert_array_equal(np.asarray(re2), np.asarray(want.real * app.tau))
+    donated = jax.jit(lambda x: x + 1, donate_argnums=0)
+    probe = jnp.zeros(4)
+    donated(probe)
+    if probe.is_deleted():  # the backend honours donation
+        assert re.is_deleted() and im.is_deleted()
+
+
+@pytest.mark.parametrize("shape", [(32, 32, 32), (16, 32, 64)])
+def test_forced_order_matches_reference(shape):
+    """With the state carried in an order other than the compiler's, a run
+    still matches the float64 reference."""
+    u = _field(shape)
+    plan = _slab_p1(shape)
+    app = NPBFT(plan, alpha=1e-3)
+    app.order = (2, 0, 1)
+    sums, u2 = app.run(jax.device_put(u, plan.input_sharding()), NITER)
+    want_sums, want_u2 = ref.npb_ft(u, NITER, 1e-3)
+    assert np.max(np.abs(np.asarray(sums) - want_sums)) / np.max(np.abs(want_sums)) < TOL
+    assert np.linalg.norm(np.asarray(u2) - want_u2) / np.linalg.norm(want_u2) < TOL
 
 
 def test_checksum_positions_follow_npb():
@@ -84,6 +150,7 @@ def test_app_refuses_plans_it_cannot_step():
 
 
 _MULTI = r"""
+import itertools
 import sys
 import numpy as np
 import jax, jax.numpy as jnp
@@ -104,15 +171,20 @@ plans = {
                            decomp="pencil", backend="alltoall", dtype=jnp.complex64),
 }
 got = {}
-for name, plan in plans.items():
+# the state in the compiler's order, and in one forced on the pencil's two
+# sharded axes
+for (name, plan), order in itertools.product(plans.items(), (None, (1, 2, 0))):
     app = NPBFT(plan, alpha=alpha)
-    assert app.tau.sharding.spec == app.spectrum.spec, name
+    if order is not None:
+        app.order = order
+    assert app.tau.sharding.spec == app.carried.spec, name
     sums, u2 = app.run(jax.device_put(u, plan.input_sharding()), 20)
-    got[name] = np.asarray(sums)
-    err = np.max(np.abs(got[name] - want_sums)) / np.max(np.abs(want_sums))
+    assert (app.entries, app.iterations) == (1, 20), name
+    err = np.max(np.abs(np.asarray(sums) - want_sums)) / np.max(np.abs(want_sums))
     field = np.linalg.norm(np.asarray(u2) - want_u2) / np.linalg.norm(want_u2)
-    assert err < 1e-5 and field < 1e-5, (name, err, field)
-    print(f"PASS {name} {err:.2e} {field:.2e}")
+    assert err < 1e-5 and field < 1e-5, (name, order, err, field)
+    got.setdefault(name, np.asarray(sums))
+    print(f"PASS {name} {order} {err:.2e} {field:.2e}")
 spread = np.max(np.abs(got["slab_p4"] - got["pencil_2x2"])) / np.max(np.abs(want_sums))
 assert spread < 1e-5, spread
 print("PASS layouts agree")

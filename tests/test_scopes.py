@@ -8,8 +8,9 @@ The compiles run in one 4-device subprocess at 64^2 (slab ``alltoall``
 at P=4 and P=1, the fused ``scatter`` pipeline, the six-step 1-D
 transform whose Twiddle rides a streaming Exchange), each plan compiled
 twice: as written, and with ``jax.named_scope`` patched to a no-op. The
-same subprocess compiles NPB FT's own steps (``repro.apps.npb_ft``:
-evolve and checksum) on a P=1 slab and a 2x2 pencil at 16x32x64.
+same subprocess compiles NPB FT's own programs (``repro.apps.npb_ft``:
+the fused iteration and the entry into planes) on a P=1 slab and a 2x2
+pencil at 16x32x64.
 """
 
 import hashlib
@@ -187,20 +188,30 @@ def test_op_class_unchanged_by_scopes(compiled, case):
 
 
 @pytest.mark.parametrize("case", APP_CASES)
-@pytest.mark.parametrize("step", ["evolve", "checksum"])
+@pytest.mark.parametrize("step", ["evolve", "checksum", "entry"])
 def test_app_ops_have_one_layer_scope(compiled, case, step):
-    """Every op of NPB FT's evolve and checksum lies under exactly its own
-    layer scope, and the op-path classifier reads it as relayout (the
-    checksum's psum as the exchange)."""
+    """Every op of NPB FT's programs with a layer scope has exactly one:
+    in the fused ``jit_iterate``, the ops of evolve and the checksum lie
+    under their own scopes and the op-path classifier reads them as
+    relayout (the checksum's psum as the exchange); the entry program
+    ``jit_to_planes`` is relayout alone."""
+    program, module = ("entry", "jit_to_planes") if step == "entry" else ("iterate", "jit_iterate")
+    layer = "relayout" if step == "entry" else step
+    text = compiled[case][program]
+    assert re.match(rf"HloModule {module}\b", text), text.splitlines()[0]
     seen = 0
-    for name, opcode, path, target, line in instructions(compiled[case][step]):
+    for name, opcode, path, target, line in instructions(text):
         if path is None:
             continue
         layers = _LAYER.findall(path)
         if not layers:
             assert boundary(opcode, line), (name, opcode, path)
             continue
-        assert layers == [step], (name, path)
+        assert len(layers) == 1, (name, path)
+        if step == "entry":
+            assert layers == [layer], (name, path)
+        if layers != [layer]:
+            continue
         # the checksum's psum is a collective, and counts as one
         want = "exchange" if opcode.startswith("all-reduce") else "relayout"
         assert trace_reduce.op_class(name, opcode, path, target) == want, (name, path)
@@ -222,9 +233,10 @@ def test_scope_names_avoid_the_op_path_patterns():
         assert name.startswith(obs.PREFIX)
         path = f"jit(<lambda>)/shard_map/{name}/transpose"
         assert trace_reduce.op_class("copy.1", "copy", path) == "relayout", name
-    for jitted, name in (("evolve", obs.EVOLVE), ("checksum", obs.CHECKSUM)):
-        path = f"jit({jitted})/{name}/mul"
-        assert trace_reduce.op_class("fusion.1", "fusion", path) == "relayout", name
+    # the op paths of evolve and the checksum in the fused iteration
+    for path in (f"jit(iterate)/{obs.EVOLVE}/mul",
+                 f"jit(iterate)/jit(checksum)/{obs.CHECKSUM}/mul"):
+        assert trace_reduce.op_class("fusion.1", "fusion", path) == "relayout", path
 
 
 def test_only_obs_trace_names_spans_and_scopes():

@@ -10,6 +10,7 @@ one process at a time may hold.
 """
 
 import os
+import re
 import threading
 
 import numpy as np
@@ -55,13 +56,16 @@ def one_chip(topo):
 
 
 def _compile(lowerable, *args):
-    """``lowerable.lower(*args).compile()`` on a daemon thread, failing
-    the test after COMPILE_LIMIT_S (the thread is abandoned, not joined)."""
+    """``lowerable.lower(*args).compile()`` (or a ``Lowered``'s
+    ``compile()``) on a daemon thread, failing the test after
+    COMPILE_LIMIT_S (the thread is abandoned, not joined)."""
     out = {}
 
     def run():
         try:
-            out["compiled"] = lowerable.lower(*args).compile()
+            lowered = lowerable if isinstance(lowerable, jax.stages.Lowered) else (
+                lowerable.lower(*args))
+            out["compiled"] = lowered.compile()
         except BaseException as e:  # re-raised in the test's thread
             out["error"] = e
 
@@ -142,18 +146,48 @@ def test_fft2_16384_forward_compiles_for_one_chip(topo, one_chip):
 
 
 def test_npb_ft_512_steps_compile_for_one_chip(topo, one_chip):
-    # NPB FT class C at one chip: the 512^3 forward and inverse of its plan
-    # and its evolve, each within the chip's HBM
-    from repro.apps import npb_ft
+    # NPB FT class C at one chip: the 512^3 forward of its plan, and the
+    # app's two programs (the fused iteration on the state's planes, the
+    # entry of the forward's output into planes), each within the chip's
+    # HBM and taking and giving every array in its default layout (one
+    # asked of jit otherwise is lost when JAX's persistent cache reads the
+    # program back); the fused iteration needs no more than the inverse
+    # alone plus the 1 GiB of the state it carries, and returns the new
+    # state in the buffers of the old
+    from repro.apps.npb_ft import NPBFT
 
     mesh = Mesh(np.array(topo.devices[:1]), ("model",))
     plan = plan_fft((512,) * 3, mesh, ndim=3, decomp="slab", backend="alltoall",
                     local_impl="jnp", dtype=jnp.complex64)
     _check(_compile(jax.jit(plan.execute), plan.input_spec()), 0)
-    spectrum = plan.input_spec(opposite=True)
-    _check(_compile(jax.jit(plan.inverse), spectrum), 0)
-    tau = jax.ShapeDtypeStruct(spectrum.shape, jnp.float32, sharding=spectrum.sharding)
-    evolve = _compile(jax.jit(npb_ft.evolve), spectrum, tau)
-    _check(evolve, 0)
-    ma = evolve.memory_analysis()
-    assert ma.temp_size_in_bytes <= 2**30 + 2**20, ma.temp_size_in_bytes
+    inverse = _compile(jax.jit(plan.inverse), plan.input_spec(opposite=True))
+    _check(inverse, 0)
+    app = NPBFT(plan)
+    programs = {name: _compile(low) for name, low in app.lower().items()}
+    for compiled in programs.values():
+        _check(compiled, 0)
+        for fmt in jax.tree.leaves((compiled.input_formats, compiled.output_formats)):
+            order = fmt.layout.major_to_minor
+            assert order == tuple(range(len(order))), fmt
+
+    def footprint(compiled):
+        # an output that aliases a donated argument lives in its buffer
+        ma = compiled.memory_analysis()
+        return (ma.argument_size_in_bytes + ma.temp_size_in_bytes + ma.output_size_in_bytes
+                - ma.alias_size_in_bytes)
+
+    assert footprint(programs["iterate"]) <= footprint(inverse) + 2**30
+    # the state comes and goes as planes in the order the iteration lays
+    # them out in: no plane split, no copy of an argument or into a result
+    text = programs["iterate"].as_text()
+    assert "X64Split" not in text
+    comps, entry = parse_hlo(text)
+    ops = {op.name: op for op in comps[entry].ops}
+    params = {op.name for op in ops.values() if op.kind == "parameter"}
+    (root,) = [op for op in ops.values() if op.raw.startswith("ROOT")]
+    copies = [op.name for op in ops.values() if op.kind == "copy"
+              and (params.intersection(op.operands) or op.name in root.operands)]
+    assert not copies, copies
+    header = text.splitlines()[0]
+    assert re.search(r"input_output_alias=\{ \{0\}: \(0, \{\}, [\w-]+\), "
+                     r"\{1\}: \(1, \{\}, [\w-]+\) \}", header), header
